@@ -35,7 +35,7 @@ from benchmarks.common import write_json_atomic
 
 from repro.configs import get_config, get_reduced
 from repro.configs.shapes import SHAPES, ShapeSpec
-from repro.dist.compat import make_mesh, set_mesh
+from repro.dist.compat import make_mesh
 from repro.dist.delayed_commit import (
     DelayedCommitConfig,
     DelayedCommitState,
@@ -87,7 +87,7 @@ def lower_phase(phase: str, compress: str, smoke: bool):
         )
         pod_shards[k] = P("pod", *inner)
 
-    with use_rules(rules), set_mesh(mesh):
+    with use_rules(rules), jax.set_mesh(mesh):
         state_sds = jax.eval_shape(partial(init_delayed_state, cfg, opt, cc), key)
         pspecs = tree_param_specs(state_sds.global_params, rules, mesh)
         podspecs = pod_prefix_specs(pspecs)

@@ -33,7 +33,7 @@ from benchmarks.common import write_json_atomic
 
 from repro.core.engine import _commit_step, make_schedule, round_fn_pallas
 from repro.core.semiring import PLUS_TIMES
-from repro.dist.compat import cost_analysis, make_mesh
+from repro.dist.compat import make_mesh
 from repro.dist.engine_sharded import input_specs_for_engine, sharded_round_fn
 from repro.graphs.generators import make_graph
 from repro.launch.dryrun import collective_stats
@@ -82,7 +82,7 @@ def fused_vs_xla_round_bytes(sched, row_update) -> dict:
             _commit_step, 0, sched=s, semiring=PLUS_TIMES, row_update=row_update
         )
     )
-    step_bytes = float(cost_analysis(step).get("bytes accessed", 0.0))
+    step_bytes = float(step.cost_analysis().get("bytes accessed", 0.0))
     fused = with_stripes(lambda s: round_fn_pallas(s, PLUS_TIMES, row_update))
     mem = fused.memory_analysis()
     pallas_bytes = float(mem.argument_size_in_bytes + mem.output_size_in_bytes)

@@ -32,7 +32,7 @@ from benchmarks.common import write_json_atomic
 
 from repro.core.engine import make_schedule
 from repro.core.semiring import PLUS_TIMES
-from repro.dist.compat import cost_analysis, make_mesh
+from repro.dist.compat import make_mesh
 from repro.dist.engine_sharded import (
     frontier_ef_init,
     frontier_pallas_round_fn,
@@ -111,7 +111,7 @@ def fused_halo_step_gate(sched, plan, row_update_q) -> dict:
         return x, newv[snd_s]
 
     xla_c = jax.jit(xla_step).lower(*avals).compile()
-    xla_step_b = float(cost_analysis(xla_c).get("bytes accessed", 0.0))
+    xla_step_b = float(xla_c.cost_analysis().get("bytes accessed", 0.0))
     return {
         "pallas_halo_step_bytes": pallas_step,
         "pallas_halo_round_bytes": S * pallas_step,

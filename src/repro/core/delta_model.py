@@ -35,17 +35,54 @@ __all__ = [
     "refit_delta_model",
     "refit_delta_models",
     "TPUCostParams",
+    "DEVICE_COST_PARAMS",
+    "PLANNING_TARGET",
+    "device_cost_params",
 ]
 
 
 @dataclasses.dataclass(frozen=True)
 class TPUCostParams:
-    """Per-chip TPU v5e constants (same as benchmarks/roofline.py)."""
+    """Per-chip constants the cost model prices a round with."""
 
-    peak_flops: float = 197e12  # bf16 FLOP/s
-    hbm_bw: float = 819e9  # B/s
-    ici_bw: float = 50e9  # B/s per link
-    collective_latency_s: float = 1e-6  # α per commit
+    peak_flops: float  # bf16 FLOP/s
+    hbm_bw: float  # B/s
+    ici_bw: float  # B/s per link
+    collective_latency_s: float  # α per commit
+
+
+#: Per-chip constants keyed by ``jax.Device.device_kind``.  Peak FLOP/s and
+#: HBM bandwidth are published figures (Google Cloud documentation, "TPU
+#: v5e"); ``ici_bw`` (1,600 Gbit/s over four links) and the per-commit
+#: collective latency are assumptions that no chip run has checked yet.
+DEVICE_COST_PARAMS = {
+    "TPU v5 lite": TPUCostParams(
+        peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9, collective_latency_s=1e-6
+    ),
+}
+
+#: The chip a run on a CPU plans for: CPU rounds price δ as v5e rounds would.
+PLANNING_TARGET = "TPU v5 lite"
+
+
+def device_cost_params(device=None) -> TPUCostParams:
+    """The :data:`DEVICE_COST_PARAMS` row for ``device`` (default: device 0).
+
+    A TPU whose ``device_kind`` has no row raises instead of borrowing another
+    chip's constants; any other platform plans for :data:`PLANNING_TARGET`.
+    """
+    import jax
+
+    device = jax.devices()[0] if device is None else device
+    if device.platform != "tpu":
+        return DEVICE_COST_PARAMS[PLANNING_TARGET]
+    try:
+        return DEVICE_COST_PARAMS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no δ cost constants for device_kind={device.device_kind!r}; "
+            f"known kinds: {sorted(DEVICE_COST_PARAMS)}"
+        ) from None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -155,7 +192,7 @@ def fit_delta_model(
         locality=loc,
         edges=graph.nnz,
         bytes_per_elem=bytes_per_elem,
-        hw=hw or TPUCostParams(),
+        hw=hw or device_cost_params(),
     )
 
 

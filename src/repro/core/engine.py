@@ -73,7 +73,13 @@ def extend_frontier(x0, semiring: Semiring):
 
 @dataclasses.dataclass(frozen=True)
 class DeviceSchedule:
-    """StripeSchedule moved to device (jnp arrays) + metadata."""
+    """StripeSchedule moved to device (jax arrays) + metadata.
+
+    Every constructor takes ``put``, which places each ``(S, P, ·)`` array:
+    ``jnp.asarray`` (whole, on the default device) unless a caller passes a
+    ``device_put`` onto a sharding, as the Solver's sharded backend does so
+    that each chip receives only its own workers' stripes from the host.
+    """
 
     n: int
     P: int
@@ -91,6 +97,24 @@ class DeviceSchedule:
     @property
     def n_slots(self) -> int:
         return self.n + 1
+
+    def worker_block(self, name: str, w0: int, w1: int) -> np.ndarray:
+        """Host copy of workers ``[w0, w1)`` of the ``(S, P, ·)`` array ``name``.
+
+        Where one device holds exactly that block (the sharded backend's
+        layout), only that shard leaves the device; otherwise the array comes
+        to the host shard by shard and is sliced there.  Never slices on the
+        device, where a sharded array would be gathered whole onto each chip.
+        """
+        arr = getattr(self, name)
+        for shard in getattr(arr, "addressable_shards", ()):
+            cols = shard.index[1]
+            if (cols.start or 0, self.P if cols.stop is None else cols.stop) == (
+                w0,
+                w1,
+            ):
+                return np.asarray(shard.data)
+        return np.asarray(arr)[:, w0:w1]
 
     # ------------------------------------------------------------------ #
     # persistence (repro.persist stores schedules as plain npz archives)
@@ -115,7 +139,25 @@ class DeviceSchedule:
         }
 
     @classmethod
-    def from_host_arrays(cls, arrays) -> "DeviceSchedule":
+    def from_stripes(cls, host: StripeSchedule, put=jnp.asarray) -> "DeviceSchedule":
+        """Move a host :class:`StripeSchedule` to the device through ``put``."""
+        return cls(
+            n=host.n,
+            P=host.P,
+            delta=host.delta,
+            S=host.S,
+            M=host.M,
+            src=put(host.src),
+            val=put(host.val),
+            dst_local=put(host.dst_local),
+            rows=put(host.rows),
+            edges=host.edges,
+            padding_overhead=host.padding_overhead,
+            block_bounds=np.asarray(host.block_bounds),
+        )
+
+    @classmethod
+    def from_host_arrays(cls, arrays, put=jnp.asarray) -> "DeviceSchedule":
         """Rebuild from :meth:`to_host_arrays` output (shape-validated)."""
         n, P = int(arrays["n"]), int(arrays["P"])
         delta, S, M = int(arrays["delta"]), int(arrays["S"]), int(arrays["M"])
@@ -137,10 +179,10 @@ class DeviceSchedule:
             delta=delta,
             S=S,
             M=M,
-            src=jnp.asarray(src),
-            val=jnp.asarray(val),
-            dst_local=jnp.asarray(dst_local),
-            rows=jnp.asarray(rows),
+            src=put(src),
+            val=put(val),
+            dst_local=put(dst_local),
+            rows=put(rows),
             edges=int(arrays["edges"]),
             padding_overhead=float(arrays["padding_overhead"]),
             block_bounds=bb.astype(np.int64) if bb.size else None,
@@ -155,6 +197,7 @@ def make_schedule(
     mode: str = "delayed",
     min_chunk: int = MIN_CHUNK,
     bounds: np.ndarray | None = None,
+    put=jnp.asarray,
 ) -> DeviceSchedule:
     """Build the device schedule for ``mode`` ∈ {sync, async, delayed}.
 
@@ -164,7 +207,8 @@ def make_schedule(
 
     ``bounds`` overrides the default :func:`balanced_blocks` partition (any
     contiguous (P + 1,) bounds, e.g. from
-    :func:`repro.graphs.partition.make_partition`).
+    :func:`repro.graphs.partition.make_partition`).  ``put`` places the
+    arrays (see :class:`DeviceSchedule`).
     """
     if bounds is None:
         bounds = balanced_blocks(graph, P)
@@ -185,20 +229,7 @@ def make_schedule(
     else:
         raise ValueError(f"unknown mode {mode!r}")
     host = build_stripe_schedule(graph, bounds, delta_eff, semiring.pad_edge_val)
-    return DeviceSchedule(
-        n=host.n,
-        P=host.P,
-        delta=host.delta,
-        S=host.S,
-        M=host.M,
-        src=jnp.asarray(host.src),
-        val=jnp.asarray(host.val),
-        dst_local=jnp.asarray(host.dst_local),
-        rows=jnp.asarray(host.rows),
-        edges=host.edges,
-        padding_overhead=host.padding_overhead,
-        block_bounds=np.asarray(host.block_bounds),
-    )
+    return DeviceSchedule.from_stripes(host, put)
 
 
 def _commit_step(

@@ -1,101 +1,40 @@
-"""Version portability for the jax sharding surface this repo drives.
+"""Small helpers over the jax mesh and export surface this repo drives.
 
-The distribution layer is written against the modern jax API (``jax.set_mesh``,
-``jax.shard_map``, ``AxisType`` meshes, ``get_abstract_mesh``).  The pinned
-toolchain ships jax 0.4.x where those either do not exist or live under
-experimental names; every call site in this repo goes through this module so
-each symbol is resolved once, here, instead of being feature-detected at every
-use.  On a current jax the wrappers are thin pass-throughs.
+Written for jax 0.9: ``jax.set_mesh``, ``jax.shard_map`` and
+``jax.sharding.AxisType`` are used directly at their call sites.  What stays
+here is what every caller would otherwise repeat: meshes with ``Auto`` axes
+(``jax.make_mesh`` defaults to ``Explicit``, under which
+``with_sharding_constraint`` asserts instead of constraining), the active
+mesh or ``None``, axis sizes of concrete and abstract meshes alike, and the
+portable executable blobs :mod:`repro.persist` stores.
 """
 
 from __future__ import annotations
 
-import contextlib
-import threading
-
 import jax
+from jax.sharding import AxisType
 
 __all__ = [
-    "AxisType",
     "current_mesh",
     "export_deserialize",
     "export_serialize",
     "make_mesh",
-    "set_mesh",
-    "shard_map",
+    "mesh_axis_sizes",
 ]
-
-try:  # jax >= 0.6
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    import enum
-
-    class AxisType(enum.Enum):
-        """Stand-in for jax.sharding.AxisType on jax 0.4.x.
-
-        Old meshes have no per-axis type; carrying the enum keeps mesh
-        construction sites identical across versions.
-        """
-
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-
-_local = threading.local()
 
 
 def make_mesh(axis_shapes, axis_names, *, axis_types=None, devices=None):
-    """``jax.make_mesh`` accepting ``axis_types`` on every jax version."""
-    try:
-        return jax.make_mesh(
-            axis_shapes, axis_names, axis_types=axis_types, devices=devices
-        )
-    except TypeError:  # jax 0.4.x: no axis_types kwarg
-        return jax.make_mesh(axis_shapes, axis_names, devices=devices)
-
-
-@contextlib.contextmanager
-def set_mesh(mesh):
-    """Activate ``mesh`` for sharding-constraint resolution (context manager).
-
-    Maps to ``jax.set_mesh`` when available, else to the legacy global mesh
-    context (``with mesh:``), which is what lets bare ``PartitionSpec``s in
-    ``with_sharding_constraint`` resolve on jax 0.4.x.
-    """
-    if hasattr(jax, "set_mesh"):
-        with jax.set_mesh(mesh):
-            yield mesh
-        return
-    prev = getattr(_local, "mesh", None)
-    _local.mesh = mesh
-    try:
-        with mesh:
-            yield mesh
-    finally:
-        _local.mesh = prev
+    """``jax.make_mesh`` whose axes default to ``AxisType.Auto``."""
+    if axis_types is None:
+        axis_types = (AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(
+        axis_shapes, axis_names, axis_types=axis_types, devices=devices
+    )
 
 
 def current_mesh():
-    """The mesh active for tracing, or ``None`` outside any mesh context.
-
-    The legacy stash is consulted first so this stays in sync with whatever
-    path :func:`set_mesh` took — on jax versions that have
-    ``get_abstract_mesh`` but not ``jax.set_mesh`` the abstract mesh is never
-    populated, and probing it first would silently report no mesh.
-    """
-    mesh = getattr(_local, "mesh", None)
-    if mesh is not None:
-        return mesh
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and mesh.axis_names:
-            return mesh
-        return None
-    # `with mesh:` entered directly rather than through set_mesh()
-    from jax._src.mesh import thread_resources
-
-    mesh = thread_resources.env.physical_mesh
+    """The mesh set by ``jax.set_mesh`` for tracing, or ``None`` outside one."""
+    mesh = jax.sharding.get_abstract_mesh()
     return mesh if mesh.axis_names else None
 
 
@@ -107,38 +46,22 @@ def mesh_axis_sizes(mesh) -> dict:
     return dict(zip(mesh.axis_names, mesh.axis_sizes))
 
 
-def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict on every jax version.
-
-    jax 0.4.x returns a one-element list of per-program dicts; newer jax
-    returns the dict directly.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
 def export_serialize(fn, args) -> bytes | None:
     """AOT-export ``jit(fn)`` for ``args``' shapes to a portable blob, or None.
 
     The blob is the :mod:`jax.export` serialization of the traced program —
-    closure constants (stripe schedules, Jacobi tables) baked in — and
-    deserializes on any same-version jax without re-running the Python that
+    closure constants (Jacobi tables, teleport vectors) baked in — and
+    deserializes on the same jax version without re-running the Python that
     built ``fn``.  Returns ``None`` (callers then keep their freshly traced
-    executable for this process only — the lower-only fallback) when:
+    executable for this process only) when:
 
-    * jax predates ``jax.export`` (the 0.4.x floor this repo's compat layer
-      targets has it, but the graceful path costs nothing);
     * the program spans **more than one device** (a shard_map export pins the
       device assignment and refuses to load into a different-width context, so
       persisting it could never hit);
     * export itself rejects the program (exotic primitives).
     """
-    try:
-        from jax import export as jax_export
-    except ImportError:  # pragma: no cover - depends on installed jax
-        return None
+    from jax import export as jax_export
+
     try:
         specs = jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tuple(args)
@@ -157,22 +80,9 @@ def export_deserialize(blob: bytes):
     A corrupt, truncated, or version-incompatible blob is a cache *miss* (the
     caller re-traces), never an error surfaced to the solve path.
     """
-    try:
-        from jax import export as jax_export
+    from jax import export as jax_export
 
+    try:
         return jax_export.deserialize(blob).call
     except Exception:
         return None
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` with the modern keyword surface on every version."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-    )

@@ -48,11 +48,12 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.engine import DeviceSchedule
 from repro.core.semiring import Semiring
-from repro.dist.compat import mesh_axis_sizes, shard_map
+from repro.dist.compat import mesh_axis_sizes
 from repro.kernels.round_block import fused_halo_step_fn
 
 __all__ = [
@@ -166,7 +167,7 @@ def sharded_round_fn_q(
 
     sched_spec = P(None, axis, None)
     x_spec = P(*((None,) * (1 + feature_dims)))
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(x_spec, sched_spec, sched_spec, sched_spec, sched_spec, P()),
@@ -233,6 +234,9 @@ class FrontierPlan:
     (``send_idx``); every shard scatters the all-gathered ``(D·H,)`` buffer
     into its own halo slots (``recv_idx``; non-resident and padding entries
     land in the dump slot).
+
+    The index arrays are host numpy when built or loaded; :meth:`placed`
+    moves them onto a mesh in the layout the halo round reads them in.
     """
 
     D: int
@@ -245,12 +249,33 @@ class FrontierPlan:
     vertex_bounds: np.ndarray  # (D + 1,) int64
     halo_sizes: np.ndarray  # (D,) int64 — |halo_in| per shard
     boundary_entries_per_round: int  # true (unpadded) halo rows shipped/round
-    src_loc: jnp.ndarray  # (D, S, P_loc, M) int32 — shard-major local src indices
-    rows_loc: jnp.ndarray  # (D, S, P_loc, delta) int32 — shard-major row slots
-    send_idx: jnp.ndarray  # (S, D, H) int32 into the flat (P_loc·delta,) chunk
-    recv_idx: jnp.ndarray  # (S, D, D·H) int32 into the local frontier
-    gather_index: jnp.ndarray  # (D, L) int32 — global slot of each local slot
-    owned_flat: jnp.ndarray  # (n,) int32 — flat (D·L) slot owning each vertex
+    src_loc: np.ndarray  # (D, S, P_loc, M) int32 — shard-major local src indices
+    rows_loc: np.ndarray  # (D, S, P_loc, delta) int32 — shard-major row slots
+    send_idx: np.ndarray  # (S, D, H) int32 into the flat (P_loc·delta,) chunk
+    recv_idx: np.ndarray  # (S, D, D·H) int32 into the local frontier
+    gather_index: np.ndarray  # (D, L) int32 — global slot of each local slot
+    owned_flat: np.ndarray  # (n,) int32 — flat (D·L) slot owning each vertex
+
+    def placed(self, mesh, axis: str = "data") -> "FrontierPlan":
+        """This plan with its arrays on ``mesh`` in the halo round's layout.
+
+        Shard-major blocks split along their leading shard axis and the
+        per-commit exchange indices along their shard axis, so each device
+        receives only its own shard straight from the host; the two small
+        global maps are replicated.
+        """
+        block = NamedSharding(mesh, P(axis, None, None, None))
+        cell = NamedSharding(mesh, P(None, axis, None))
+        whole = NamedSharding(mesh, P())
+        return dataclasses.replace(
+            self,
+            src_loc=jax.device_put(self.src_loc, block),
+            rows_loc=jax.device_put(self.rows_loc, block),
+            send_idx=jax.device_put(self.send_idx, cell),
+            recv_idx=jax.device_put(self.recv_idx, cell),
+            gather_index=jax.device_put(self.gather_index, whole),
+            owned_flat=jax.device_put(self.owned_flat, whole),
+        )
 
     # ------------------------------------------------------------------ #
     # Wire accounting (the replicated column is the engine's flush_bytes)
@@ -293,12 +318,12 @@ class FrontierPlan:
             vertex_bounds=np.asarray(arrays["vertex_bounds"], dtype=np.int64),
             halo_sizes=np.asarray(arrays["halo_sizes"], dtype=np.int64),
             boundary_entries_per_round=int(arrays["boundary_entries_per_round"]),
-            src_loc=jnp.asarray(arrays["src_loc"]),
-            rows_loc=jnp.asarray(arrays["rows_loc"]),
-            send_idx=jnp.asarray(arrays["send_idx"]),
-            recv_idx=jnp.asarray(arrays["recv_idx"]),
-            gather_index=jnp.asarray(arrays["gather_index"]),
-            owned_flat=jnp.asarray(arrays["owned_flat"]),
+            src_loc=np.asarray(arrays["src_loc"]),
+            rows_loc=np.asarray(arrays["rows_loc"]),
+            send_idx=np.asarray(arrays["send_idx"]),
+            recv_idx=np.asarray(arrays["recv_idx"]),
+            gather_index=np.asarray(arrays["gather_index"]),
+            owned_flat=np.asarray(arrays["owned_flat"]),
         )
         if (
             plan.send_idx.shape != (S, D, H)
@@ -347,25 +372,29 @@ def build_plan_shard(
     reused when a mutation leaves those workers' stripes unchanged.  Dump
     slots are stored as ``-1`` sentinels because the real dump index ``L - 1``
     depends on *every* shard's halo size — :func:`assemble_frontier_plan`
-    substitutes it.
+    substitutes it.  Reads its workers through
+    :meth:`DeviceSchedule.worker_block`, so a sharded schedule hands over
+    only this shard's stripes.
     """
-    src_d = np.asarray(sched.src)[:, w0:w1, :].astype(np.int64)
-    real_d = np.asarray(sched.dst_local)[:, w0:w1, :] < sched.delta
-    remote = real_d & ((src_d < vb_lo) | (src_d >= vb_hi))
-    halo = np.unique(src_d[remote])
+    # int32 throughout: vertex ids and local slots are < n < 2**31, and at
+    # scale the (S, P_loc, M) temporaries dominate the host's plan build.
+    src_d = sched.worker_block("src", w0, w1)
+    real_d = sched.worker_block("dst_local", w0, w1) < sched.delta
+    own = real_d & (src_d >= vb_lo) & (src_d < vb_hi)
+    rem = real_d & ~own
+    remote_src = src_d[rem]
+    halo = np.unique(remote_src)
     owned_d = int(vb_hi - vb_lo)
 
-    loc = np.full(src_d.shape, -1, dtype=np.int64)
-    own = real_d & (src_d >= vb_lo) & (src_d < vb_hi)
-    loc[own] = src_d[own] - vb_lo
-    rem = real_d & ~own
+    loc = np.full(src_d.shape, -1, dtype=np.int32)
+    loc[own] = src_d[own] - np.int32(vb_lo)
     if halo.size:
-        loc[rem] = owned_d + np.searchsorted(halo, src_d[rem])
-    rr = np.asarray(sched.rows)[:, w0:w1, :].astype(np.int64)
-    rows_loc = np.where(rr >= sched.n, -1, rr - vb_lo)
+        loc[rem] = owned_d + np.searchsorted(halo, remote_src).astype(np.int32)
+    rr = sched.worker_block("rows", w0, w1)
+    rows_loc = np.where(rr >= sched.n, np.int32(-1), rr - np.int32(vb_lo))
     return {
-        "halo": halo,
-        "src_loc": loc.astype(np.int32),
+        "halo": halo.astype(np.int64),
+        "src_loc": loc,
         "rows_loc": rows_loc.astype(np.int32),
     }
 
@@ -427,9 +456,9 @@ def assemble_frontier_plan(
 
     # Boundary traffic: per (step, shard), the committed rows some other
     # shard keeps a halo copy of.  H pads to the worst (step, shard) cell.
-    boundary = (
-        np.unique(np.concatenate(halo)) if halo_sizes.sum() else np.zeros(0, np.int64)
-    )
+    is_boundary = np.zeros(n + 1, dtype=bool)  # slot n (dump) is never shipped
+    for h in halo:
+        is_boundary[h] = True
     chunks = [
         [
             rows[s, d * P_loc : (d + 1) * P_loc, :].reshape(-1).astype(np.int64)
@@ -437,10 +466,7 @@ def assemble_frontier_plan(
         ]
         for s in range(S)
     ]
-    send_pos = [
-        [np.nonzero((c < n) & np.isin(c, boundary))[0] for c in chunks[s]]
-        for s in range(S)
-    ]
+    send_pos = [[np.nonzero(is_boundary[c])[0] for c in chunks[s]] for s in range(S)]
     counts = np.array([[p.size for p in row] for row in send_pos], dtype=np.int64)
     H = max(1, int(counts.max())) if counts.size else 1
 
@@ -477,12 +503,12 @@ def assemble_frontier_plan(
         vertex_bounds=vb,
         halo_sizes=halo_sizes,
         boundary_entries_per_round=int(counts.sum()),
-        src_loc=jnp.asarray(src_loc),
-        rows_loc=jnp.asarray(rows_loc),
-        send_idx=jnp.asarray(send_idx),
-        recv_idx=jnp.asarray(recv_idx),
-        gather_index=jnp.asarray(gather_index),
-        owned_flat=jnp.asarray(owned_flat),
+        src_loc=src_loc,
+        rows_loc=rows_loc,
+        send_idx=send_idx,
+        recv_idx=recv_idx,
+        gather_index=gather_index,
+        owned_flat=owned_flat,
     )
 
 
@@ -552,7 +578,7 @@ def frontier_sharded_round_fn(
     cell = P(None, axis, None)
     block = P(axis, None, None, None)
     x_spec = P(axis, *((None,) * (1 + feature_dims)))
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(x_spec, block, cell, cell, cell, block, cell, cell, P()),
@@ -717,7 +743,7 @@ def frontier_pallas_round_fn(
     block = P(axis, None, None, None)
     x_spec = P(axis, *((None,) * (1 + feature_dims)))
     ef_spec = P(axis, *((None,) * (2 + feature_dims)))
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

@@ -37,6 +37,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.core import eval_jaxpr
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -49,28 +50,8 @@ __all__ = [
     "resolve_interpret",
 ]
 
-# Version portability (same spirit as repro.dist.compat): the typed
-# compiler-params class is CompilerParams on current jax, TPUCompilerParams
-# on 0.4.x; eval_jaxpr lives in jax.core on 0.4.x and jax.extend.core later.
-_COMPILER_PARAMS_CLS = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-
-try:  # pragma: no cover - depends on installed jax
-    from jax.extend.core import eval_jaxpr as _eval_jaxpr
-except ImportError:
-    from jax.core import eval_jaxpr as _eval_jaxpr
-
-
-def _sequential_grid_params() -> dict:
-    """``compiler_params`` pinning the grid sequential (commit order) on TPU."""
-    if _COMPILER_PARAMS_CLS is not None:
-        return {
-            "compiler_params": _COMPILER_PARAMS_CLS(
-                dimension_semantics=("arbitrary",)
-            )
-        }
-    return {"compiler_params": dict(mosaic=dict(dimension_semantics=("arbitrary",)))}
+# Pins the grid sequential on TPU: commit step s reads steps < s.
+_SEQUENTIAL_GRID = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
@@ -171,7 +152,7 @@ def fused_round_fn_q(
             old = x[rows]
             c_vals = [c[...].reshape(shape) for c, shape in zip(c_refs, c_shapes)]
             leaves = [r[...].reshape(a.shape) for r, a in zip(q_refs, q_avals)]
-            (new,) = _eval_jaxpr(jaxpr, c_vals, old, reduced, rows, *leaves)
+            (new,) = eval_jaxpr(jaxpr, c_vals, old, reduced, rows, *leaves)
             # The flush: commit this step's chunks into the VMEM frontier.
             if feat:
                 chunk = new.reshape((-1,) + feat).astype(x_ref.dtype)
@@ -195,7 +176,7 @@ def fused_round_fn_q(
             # x_ext in ↔ out: commits stay visible across sequential steps
             input_output_aliases={4 + n_consts + n_q: 0},
             interpret=interp,
-            **_sequential_grid_params(),
+            compiler_params=_SEQUENTIAL_GRID,
         )(sched.src, sched.val, sched.dst_local, sched.rows, *c_in, *q_in, x_ext)
 
     return rnd
@@ -282,7 +263,7 @@ def fused_halo_step_fn(
             old = x[rows_l]
             c_vals = [c[...].reshape(shape) for c, shape in zip(c_refs, c_shapes)]
             leaves = [r[...].reshape(a.shape) for r, a in zip(q_refs, q_avals)]
-            (new,) = _eval_jaxpr(jaxpr, c_vals, old, reduced, rows_g, *leaves)
+            (new,) = eval_jaxpr(jaxpr, c_vals, old, reduced, rows_g, *leaves)
             chunk = new.reshape((-1,) + feat).astype(x_ref.dtype)
             # Owner-computes publish: commit this shard's chunk in VMEM.
             if feat:
@@ -304,7 +285,7 @@ def fused_halo_step_fn(
             ],
             input_output_aliases={len(ins) - 1: 0},
             interpret=interp,
-            **_sequential_grid_params(),
+            compiler_params=_SEQUENTIAL_GRID,
         )(*ins)
 
     return step

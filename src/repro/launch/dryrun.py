@@ -33,7 +33,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ALIASES, all_arch_ids, get_config
 from repro.configs.shapes import SHAPES, applicable_shapes
-from repro.dist.compat import cost_analysis, set_mesh
 from repro.dist.sharding import Rules, tree_param_specs, use_rules
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import input_specs
@@ -175,7 +174,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool, kv_quant: bool = Fal
     arg_sh = tuple(named(mesh, s, d) for s, d in zip(arg_shard_specs, arg_specs))
 
     t0 = time.time()
-    with use_rules(rules), set_mesh(mesh):
+    with use_rules(rules), jax.set_mesh(mesh):
         if kind == "train":
             from repro.train.optimizer import MixedPrecision
 
@@ -226,7 +225,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool, kv_quant: bool = Fal
         t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = collective_stats(hlo)
 

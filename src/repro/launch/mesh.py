@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.dist.compat import AxisType, make_mesh
+from repro.dist.compat import make_mesh
 
 __all__ = ["make_production_mesh", "make_host_mesh"]
 
@@ -24,7 +24,7 @@ __all__ = ["make_production_mesh", "make_host_mesh"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -32,8 +32,4 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = min(model, n // data)
-    return make_mesh(
-        (data, model),
-        ("data", "model"),
-        axis_types=(AxisType.Auto,) * 2,
-    )
+    return make_mesh((data, model), ("data", "model"))

@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.dist.compat import current_mesh, shard_map
+from repro.dist.compat import current_mesh
 from repro.dist.sharding import logical
 from repro.models.config import ModelConfig
 from repro.models.layers import (
@@ -426,7 +426,7 @@ def _moe_shardmap(p, cfg: ModelConfig, x, mesh):
         return y.reshape(b, s, d), lb
 
     seq_spec = "model" if a2a_path else None
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

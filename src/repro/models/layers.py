@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.dist.compat import shard_map
 
 F32 = jnp.float32
 
@@ -338,7 +337,7 @@ def seq_parallel_decode_attention(
         in_specs += [sc_spec, sc_spec]
         out_specs += [sc_spec, sc_spec]
         args += list(scales)
-    res = shard_map(
+    res = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=tuple(in_specs),

@@ -12,8 +12,10 @@ wrong answer.  A solver namespace hashes together
 * the solver shape knobs (``n_workers``, ``partition_method``, ``min_chunk``);
 * the solver's effective ``tol``/``max_rounds`` (constructor overrides
   applied), so different convergence regimes never share a δ-model;
-* the **environment** (cache format, repro / jax / numpy versions) — a version
-  bump silently retires every old namespace.
+* the **environment** (cache format, repro / jax / numpy versions, and the
+  platform and ``device_kind`` of device 0) — a version bump silently retires
+  every old namespace, and a CPU-filled cache never hands a chip process its
+  δ-model or observation log.
 
 Known limit: *source edits* to schedule/engine construction code are not
 content-hashed (package version strings don't change in a dev checkout, and
@@ -46,7 +48,8 @@ __all__ = [
 
 # Bump to retire every existing cache entry (layout or semantics change).
 # 2: FrontierPlan src_loc/rows_loc went shard-major (D, S, P_loc, ·).
-CACHE_FORMAT = 2
+# 3: the environment part names the device (platform, device_kind).
+CACHE_FORMAT = 3
 
 try:  # installed package
     import importlib.metadata
@@ -56,11 +59,17 @@ except Exception:  # pragma: no cover - PYTHONPATH runs carry no dist metadata
     _REPRO_VERSION = "0.1.0"
 
 
-def env_fingerprint() -> str:
-    """The toolchain part of every namespace key (mismatch ⇒ cold build)."""
+def env_fingerprint(device=None) -> str:
+    """The toolchain and device part of every namespace key (mismatch ⇒ cold).
+
+    ``device`` defaults to ``jax.devices()[0]``: the device the solver's
+    executables, probes and timings belong to.
+    """
+    device = jax.devices()[0] if device is None else device
     return (
         f"format{CACHE_FORMAT}-repro{_REPRO_VERSION}"
         f"-jax{jax.__version__}-numpy{np.__version__}"
+        f"-{device.platform}-{device.device_kind}"
     )
 
 
@@ -129,9 +138,9 @@ def plan_shard_fingerprint(sched, vb_lo: int, vb_hi: int, w0: int, w1: int) -> s
         str(int(sched.delta)).encode(),
         str(int(vb_lo)).encode(),
         str(int(vb_hi)).encode(),
-        np.ascontiguousarray(np.asarray(sched.src)[:, w0:w1]).tobytes(),
-        np.ascontiguousarray(np.asarray(sched.dst_local)[:, w0:w1]).tobytes(),
-        np.ascontiguousarray(np.asarray(sched.rows)[:, w0:w1]).tobytes(),
+        np.ascontiguousarray(sched.worker_block("src", w0, w1)).tobytes(),
+        np.ascontiguousarray(sched.worker_block("dst_local", w0, w1)).tobytes(),
+        np.ascontiguousarray(sched.worker_block("rows", w0, w1)).tobytes(),
     )
 
 

@@ -32,6 +32,7 @@ import threading
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.delta_model import DeltaModel
@@ -177,12 +178,13 @@ class SolverCache:
     def save_schedule(self, sched: DeviceSchedule) -> None:
         _save_npz(self._sched_path(sched.delta), sched.to_host_arrays())
 
-    def load_schedule(self, delta: int) -> DeviceSchedule | None:
+    def load_schedule(self, delta: int, put=jnp.asarray) -> DeviceSchedule | None:
+        """The persisted schedule for ``delta``, placed by ``put``, or None."""
         path = self._sched_path(delta)
         try:
             _read_fault(path)
             with np.load(path, allow_pickle=False) as arrays:
-                sched = DeviceSchedule.from_host_arrays(arrays)
+                sched = DeviceSchedule.from_host_arrays(arrays, put)
             if sched.delta != int(delta):
                 return None
             return sched

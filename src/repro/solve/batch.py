@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.engine import round_fn_pallas_q, round_fn_q
+from repro.core.engine import round_fn_pallas_q, round_fn_q_dyn, schedule_args
 from repro.ft.inject import fire
 
 __all__ = ["BatchResult", "BatchStepper", "RetiredQuery", "solve_batch"]
@@ -57,7 +57,12 @@ class BatchResult:
 
 
 def _batched_round(solver, sched, backend: str, frontier: str, feature_dims: int = 0):
-    """Build ``(X_ext, qb) -> X_ext`` running one round for all Q queries.
+    """Build ``(X_ext, qb, *args) -> X_ext`` running one round for all Q queries.
+
+    Returns ``(rnd, args)``: ``args`` are the schedule (and halo-plan) arrays,
+    which the compiled loop takes as arguments instead of closing over them,
+    so XLA never embeds gigabytes of stripes in the executable.  The pallas
+    kernel bakes its schedule into the grid and takes none.
 
     ``feature_dims`` is 0 for vector frontiers (``X_ext`` is ``(Q, n+1)``)
     and 1 for matrix frontiers (``(Q, n+1, F)``); the sharded builders need
@@ -71,9 +76,12 @@ def _batched_round(solver, sched, backend: str, frontier: str, feature_dims: int
             "batched halo solves use backend='sharded', frontier='halo' "
             "(backend='pallas' fuses per-shard kernels and cannot be vmapped)"
         )
-    if backend in ("jit", "pallas"):
-        builder = round_fn_q if backend == "jit" else round_fn_pallas_q
-        return jax.vmap(builder(sched, sr, solver._row_update_q), in_axes=(0, 0))
+    if backend == "jit":
+        rnd = round_fn_q_dyn(sched, sr, solver._row_update_q)
+        return jax.vmap(rnd, in_axes=(0, 0) + (None,) * 4), schedule_args(sched)
+    if backend == "pallas":
+        rnd = round_fn_pallas_q(sched, sr, solver._row_update_q)
+        return jax.vmap(rnd, in_axes=(0, 0)), ()
     if backend != "sharded":
         raise ValueError(
             f"batch backend must be 'jit', 'pallas', or 'sharded': {backend!r}"
@@ -87,8 +95,7 @@ def _batched_round(solver, sched, backend: str, frontier: str, feature_dims: int
             feature_dims=feature_dims,
         )
         vm = jax.vmap(base, in_axes=(0, None, None, None, None, 0))
-        args = (sched.src, sched.val, sched.dst_local, sched.rows)
-        return lambda X, qb: vm(X, *args, qb)
+        return (lambda X, qb, *args: vm(X, *args, qb)), schedule_args(sched)
     from repro.dist.engine_sharded import frontier_plan_args, frontier_round_ext_fn
 
     plan = solver.frontier_plan(sched)
@@ -97,22 +104,21 @@ def _batched_round(solver, sched, backend: str, frontier: str, feature_dims: int
         feature_dims=feature_dims,
     )
     args = frontier_plan_args(sched, plan)
-    vm = jax.vmap(ext, in_axes=(0, 0) + (None,) * len(args))
-    return lambda X, qb: vm(X, qb, *args)
+    return jax.vmap(ext, in_axes=(0, 0) + (None,) * len(args)), args
 
 
 def _make_batch_solve_fn(rnd, residual_fn):
-    """``(X_ext, qb, tol, max_rounds) -> carry`` over a batched round fn."""
+    """``(X_ext, qb, tol, max_rounds, *args) -> carry`` over a batched round fn."""
     res_fn = jax.vmap(residual_fn, in_axes=(0, 0))
 
-    def solve_loop(X_ext, qb, tol, max_rounds):
+    def solve_loop(X_ext, qb, tol, max_rounds, *args):
         def cond(carry):
             _, _, rounds, converged, _ = carry
             return jnp.logical_and(rounds < max_rounds, ~jnp.all(converged))
 
         def body(carry):
             X, _, rounds, converged, rpq = carry
-            X_new = rnd(X, qb)
+            X_new = rnd(X, qb, *args)
             res = res_fn(X[:, :-1], X_new[:, :-1]).astype(jnp.float32)
             # stamp only at first convergence; never-converged queries keep 0
             just_converged = jnp.logical_and(~converged, res <= tol)
@@ -133,7 +139,7 @@ def _make_batch_solve_fn(rnd, residual_fn):
 
 
 def _make_open_batch_solve_fn(rnd, residual_fn):
-    """``(X_ext, qb, conv0, tol, max_rounds) -> carry`` for an *open* batch.
+    """``(X_ext, qb, conv0, tol, max_rounds, *args) -> carry`` for an *open* batch.
 
     Two deltas from :func:`_make_batch_solve_fn`, both load-bearing for
     continuous batching:
@@ -148,14 +154,14 @@ def _make_open_batch_solve_fn(rnd, residual_fn):
     """
     res_fn = jax.vmap(residual_fn, in_axes=(0, 0))
 
-    def solve_loop(X_ext, qb, conv0, tol, max_rounds):
+    def solve_loop(X_ext, qb, conv0, tol, max_rounds, *args):
         def cond(carry):
             _, _, rounds, converged, _ = carry
             return jnp.logical_and(rounds < max_rounds, ~jnp.all(converged))
 
         def body(carry):
             X, res_prev, rounds, converged, rpq = carry
-            X_new = rnd(X, qb)
+            X_new = rnd(X, qb, *args)
             res = res_fn(X[:, :-1], X_new[:, :-1]).astype(jnp.float32)
             just_converged = jnp.logical_and(~converged, res <= tol)
             rpq = jnp.where(just_converged, rounds + 1, rpq)
@@ -325,22 +331,23 @@ class BatchStepper:
 
     # ---------------------------------------------------------------- run #
     def _compiled_loop(self, X_ext, qb, conv0, tol_a, rounds_a):
-        return self.solver.compile_cached(
+        """The compiled open-batch loop and the arrays it takes after ``rounds``."""
+        rnd, args = _batched_round(
+            self.solver, self.sched, self.backend, self.frontier,
+            feature_dims=len(self._feat),
+        )
+        fn = self.solver.compile_cached(
             self._key,
-            _make_open_batch_solve_fn(
-                _batched_round(
-                    self.solver, self.sched, self.backend, self.frontier,
-                    feature_dims=len(self._feat),
-                ),
-                self.solver.problem.residual,
-            ),
+            _make_open_batch_solve_fn(rnd, self.solver.problem.residual),
             X_ext,
             qb,
             conv0,
             tol_a,
             rounds_a,
+            *args,
             portable=self._portable,
         )
+        return fn, args
 
     def evict_all(self) -> list:
         """Clear every occupied slot and return their tags (fault recovery).
@@ -376,8 +383,8 @@ class BatchStepper:
         conv0 = jnp.asarray(~occ)
         tol_a = jnp.asarray(self.tol, jnp.float32)
         rounds_a = jnp.asarray(quantum, jnp.int32)
-        fn = self._compiled_loop(X_ext, qb, conv0, tol_a, rounds_a)
-        X_new, res, r, conv, rpq = fn(X_ext, qb, conv0, tol_a, rounds_a)
+        fn, args = self._compiled_loop(X_ext, qb, conv0, tol_a, rounds_a)
+        X_new, res, r, conv, rpq = fn(X_ext, qb, conv0, tol_a, rounds_a, *args)
         X_new.block_until_ready()
         r = int(r)
         # np.array (copy), not np.asarray: device buffers are read-only and
@@ -512,20 +519,20 @@ def solve_batch(
 
         key_tail = (mesh_axis_sizes(solver._default_mesh())[solver.mesh_axis],)
 
+    rnd, args = _batched_round(solver, sched, backend, frontier, len(feat))
+
     def compiled_loop(X_cur, qb_cur):
         """The fused loop for the current active size (cached per size)."""
         return solver.compile_cached(
             ("batch", backend, frontier, sched.delta, X_cur.shape[0])
             + key_tail
             + fk,
-            _make_batch_solve_fn(
-                _batched_round(solver, sched, backend, frontier, len(feat)),
-                problem.residual,
-            ),
+            _make_batch_solve_fn(rnd, problem.residual),
             X_cur,
             qb_cur,
             tol_a,
             jnp.asarray(max_rounds, jnp.int32),
+            *args,
             # a >1-device shard_map export pins its device assignment and
             # could never load — skip the store instead of exporting to waste
             portable=key_tail in ((), (1,)),
@@ -549,7 +556,9 @@ def solve_batch(
             chunk = min(chunk, compact_every)
         fn = compiled_loop(X_ext, qb)
         compile_time_s += solver._last_compile_s
-        X_new, res, r, conv, rpq = fn(X_ext, qb, tol_a, jnp.asarray(chunk, jnp.int32))
+        X_new, res, r, conv, rpq = fn(
+            X_ext, qb, tol_a, jnp.asarray(chunk, jnp.int32), *args
+        )
         X_new.block_until_ready()
         r = int(r)
         rounds_done += r

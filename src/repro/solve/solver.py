@@ -448,7 +448,7 @@ class Solver:
         delta_eff = self.resolve_delta(delta)
         sched = self._schedules.get(delta_eff)
         if sched is None and self.persist is not None:
-            sched = self.persist.load_schedule(delta_eff)
+            sched = self.persist.load_schedule(delta_eff, put=self._schedule_put())
             if sched is not None:
                 self._schedules[delta_eff] = sched
                 self.stats["cache_loads"] += 1
@@ -463,6 +463,7 @@ class Solver:
                 mode="delayed",
                 min_chunk=self.min_chunk,
                 bounds=self.bounds,
+                put=self._schedule_put(),
             )
             self._schedules[delta_eff] = sched
             self.stats["schedule_builds"] += 1
@@ -499,20 +500,7 @@ class Solver:
         host = assemble_stripe_schedule(
             self._sched_graph, bounds, delta_eff, pad_val, stripes
         )
-        sched = DeviceSchedule(
-            n=host.n,
-            P=host.P,
-            delta=host.delta,
-            S=host.S,
-            M=host.M,
-            src=jnp.asarray(host.src),
-            val=jnp.asarray(host.val),
-            dst_local=jnp.asarray(host.dst_local),
-            rows=jnp.asarray(host.rows),
-            edges=host.edges,
-            padding_overhead=host.padding_overhead,
-            block_bounds=np.asarray(host.block_bounds),
-        )
+        sched = DeviceSchedule.from_stripes(host, self._schedule_put())
         self._schedules[delta_eff] = sched
         if built:
             self.stats["schedule_builds"] += 1
@@ -521,6 +509,22 @@ class Solver:
         self.persist.save_schedule(sched)
         return sched
 
+    def _schedule_put(self):
+        """How schedule arrays reach the device (see :class:`DeviceSchedule`).
+
+        The sharded backend places them straight into the worker-axis
+        sharding its rounds read them in, so each chip receives only its own
+        workers' stripes and no chip ever holds the whole schedule; every
+        other backend keeps them whole on the default device.
+        """
+        if self.default_backend != "sharded":
+            return jnp.asarray
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        sharding = NamedSharding(self._default_mesh(), P(None, self.mesh_axis, None))
+        return lambda a: jax.device_put(a, sharding)
+
     def frontier_plan(self, sched: DeviceSchedule):
         """The cached owner-computes halo plan for ``sched`` on this mesh.
 
@@ -528,7 +532,9 @@ class Solver:
         per-shard pieces from the shared content-addressed store (only the
         shards whose workers a mutation touched rebuild; the global assembly
         — exchange indices, gather maps — is recomputed cheaply either way).
-        ``plan_builds`` counts plans with ≥ 1 cold shard.
+        ``plan_builds`` counts plans with ≥ 1 cold shard.  Plans are built on
+        the host and placed shard by shard onto the mesh
+        (:meth:`FrontierPlan.placed`).
         """
         from repro.dist.compat import mesh_axis_sizes
         from repro.dist.engine_sharded import (
@@ -538,13 +544,15 @@ class Solver:
             plan_shard_bounds,
         )
 
-        D = mesh_axis_sizes(self._default_mesh())[self.mesh_axis]
+        mesh = self._default_mesh()
+        D = mesh_axis_sizes(mesh)[self.mesh_axis]
         key = (sched.delta, D)
-        plan = self._plans.get(key)
-        if plan is None and self.persist is not None:
+        if key in self._plans:
+            return self._plans[key]
+        plan = None
+        if self.persist is not None:
             plan = self.persist.load_plan(sched.delta, D)
             if plan is not None:
-                self._plans[key] = plan
                 self.stats["cache_loads"] += 1
         if plan is None and self.persist is not None and sched.P % D == 0:
             from repro.persist.keys import plan_shard_fingerprint
@@ -569,7 +577,6 @@ class Solver:
                     self.stats["plan_shard_loads"] += 1
                 pieces.append(piece)
             plan = assemble_frontier_plan(sched, D, pieces)
-            self._plans[key] = plan
             if built:
                 self.stats["plan_builds"] += 1
             else:
@@ -577,10 +584,11 @@ class Solver:
             self.persist.save_plan(plan)
         if plan is None:
             plan = make_frontier_plan(sched, D)
-            self._plans[key] = plan
             self.stats["plan_builds"] += 1
             if self.persist is not None:
                 self.persist.save_plan(plan)
+        plan = plan.placed(mesh, self.mesh_axis)
+        self._plans[key] = plan
         return plan
 
     # ------------------------------------------------------------------ #
@@ -1071,9 +1079,9 @@ class Solver:
             if not fits:
                 del self._schedules[delta_eff]
                 continue
-            src = np.asarray(sched.src).copy()
-            val = np.asarray(sched.val).copy()
-            dst_local = np.asarray(sched.dst_local).copy()
+            src = np.array(sched.src)
+            val = np.array(sched.val)
+            dst_local = np.array(sched.dst_local)
             for w, st in stripes.items():
                 m = st["src"].shape[1]
                 src[:, w, :] = 0
@@ -1083,11 +1091,12 @@ class Solver:
                 dst_local[:, w, :] = delta_eff
                 dst_local[:, w, :m] = st["dst_local"]
                 # rows[:, w] is untouched: it depends only on (lo, hi, δ, n)
+            put = self._schedule_put()
             self._schedules[delta_eff] = dataclasses.replace(
                 sched,
-                src=jnp.asarray(src),
-                val=jnp.asarray(val),
-                dst_local=jnp.asarray(dst_local),
+                src=put(src),
+                val=put(val),
+                dst_local=put(dst_local),
                 edges=self._sched_graph.nnz,
                 padding_overhead=src.size / max(self._sched_graph.nnz, 1),
             )
@@ -1195,15 +1204,12 @@ class Solver:
     # ------------------------------------------------------------------ #
     def _default_mesh(self):
         if self._mesh is None:
-            from repro.dist.compat import AxisType, make_mesh
+            from repro.dist.compat import make_mesh
 
             ndev = len(jax.devices())
             size = math.gcd(self.n_workers, ndev)
             self._mesh = make_mesh(
-                (size,),
-                (self.mesh_axis,),
-                axis_types=(AxisType.Auto,),
-                devices=jax.devices()[:size],
+                (size,), (self.mesh_axis,), devices=jax.devices()[:size]
             )
         return self._mesh
 

@@ -8,7 +8,12 @@ import pytest
 from repro.configs import all_arch_ids, get_config
 from repro.configs.shapes import SHAPES, applicable_shapes
 from repro.core.access_matrix import access_matrix, locality_fraction
-from repro.core.delta_model import TPUCostParams, fit_delta_model
+from repro.core.delta_model import (
+    DEVICE_COST_PARAMS,
+    PLANNING_TARGET,
+    device_cost_params,
+    fit_delta_model,
+)
 from repro.dist.sharding import Rules
 from repro.graphs.formats import build_stripe_schedule
 from repro.graphs.generators import make_graph
@@ -52,6 +57,16 @@ class TestDeltaModel:
     def test_cost_model_penalizes_fine_delta(self):
         m = fit_delta_model(self.g, 16, 20, 12, delta_min=16)
         assert m.round_cost_s(16) > m.round_cost_s(m.B)
+
+    def test_cost_params_follow_device_kind(self):
+        from types import SimpleNamespace as Dev
+
+        v5e = device_cost_params(Dev(platform="tpu", device_kind="TPU v5 lite"))
+        assert (v5e.peak_flops, v5e.hbm_bw) == (197e12, 819e9)
+        cpu = device_cost_params(Dev(platform="cpu", device_kind="cpu"))
+        assert cpu == DEVICE_COST_PARAMS[PLANNING_TARGET]
+        with pytest.raises(ValueError, match="TPU v9"):
+            device_cost_params(Dev(platform="tpu", device_kind="TPU v9"))
 
     def test_best_delta_in_grid(self):
         m = fit_delta_model(self.g, 16, 20, 12, delta_min=16)

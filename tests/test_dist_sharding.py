@@ -6,7 +6,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import all_arch_ids, get_config
-from repro.dist.compat import make_mesh, set_mesh
+from repro.dist.compat import make_mesh
 from repro.dist.sharding import (
     Rules,
     current_rules,
@@ -60,7 +60,7 @@ class TestLogical:
     def test_applies_constraint_under_mesh(self):
         mesh = make_mesh((1, 1), ("data", "model"))
         x = jnp.ones((4, 8))
-        with use_rules(Rules.default(seq_axis="model")), set_mesh(mesh):
+        with use_rules(Rules.default(seq_axis="model")), jax.set_mesh(mesh):
             y = jax.jit(lambda a: logical(a, ("batch", "embed")))(x)
         assert jnp.array_equal(y, x)
 

@@ -18,6 +18,7 @@ empty but the full exchange machinery still runs); under
 the same tests exercise real 8-way sharding.
 """
 
+import dataclasses
 import math
 
 import jax
@@ -153,6 +154,34 @@ class TestFrontierPlan:
         sched = make_schedule(GRAPH_PR, 6, 32, PLUS_TIMES)
         with pytest.raises(ValueError, match="not divisible"):
             make_frontier_plan(sched, 4)
+
+    def test_worker_block_reads_the_matching_shard(self):
+        """Plan builds copy a shard's own stripes, never slicing on device."""
+        from types import SimpleNamespace
+
+        sched = make_schedule(GRAPH_PR, N_WORKERS, 32, PLUS_TIMES)
+        host = np.asarray(sched.src)
+        np.testing.assert_array_equal(sched.worker_block("src", 2, 4), host[:, 2:4])
+        shard = SimpleNamespace(
+            index=(slice(None), slice(2, 4), slice(None)), data=host[:, 2:4]
+        )
+        fake = dataclasses.replace(
+            sched, src=SimpleNamespace(addressable_shards=[shard])
+        )
+        assert fake.worker_block("src", 2, 4) is shard.data
+
+    def test_sharded_solver_places_schedule_and_plan_per_shard(self):
+        solver = Solver(
+            GRAPH_PR, pagerank_problem(), n_workers=N_WORKERS, delta=64,
+            backend="sharded", frontier="halo",
+        )
+        sched = solver.schedule()
+        plan = solver.frontier_plan(sched)
+        D = mesh_width()
+        for arr in (sched.src, sched.val, plan.send_idx):
+            assert len(arr.addressable_shards) == D
+            assert arr.addressable_shards[0].data.shape[1] == arr.shape[1] // D
+        assert plan.src_loc.addressable_shards[0].data.shape[0] == 1
 
     def test_plan_cached_on_solver(self):
         solver = Solver(

@@ -17,7 +17,12 @@ Acceptance-criteria coverage for the persistent solver cache:
 import numpy as np
 import pytest
 
-from repro.core.delta_model import DeltaModel, TPUCostParams, refit_delta_model
+from repro.core.delta_model import (
+    DEVICE_COST_PARAMS,
+    PLANNING_TARGET,
+    DeltaModel,
+    refit_delta_model,
+)
 from repro.graphs.generators import make_graph
 from repro.solve import (
     Solver,
@@ -139,6 +144,24 @@ class TestInvalidation:
         assert_cold(other)
         np.testing.assert_array_equal(r_cold.x, r_other.x)
 
+    def test_device_change_is_cold(self, tmp_path, monkeypatch):
+        """A cache filled on one platform never warms a process on another."""
+        from types import SimpleNamespace as Dev
+
+        from repro.persist import keys
+
+        chip = Dev(platform="tpu", device_kind="TPU v5 lite")
+        cpu = Dev(platform="cpu", device_kind="cpu")
+        assert keys.env_fingerprint(chip) != keys.env_fingerprint(cpu)
+        cold = pr_solver(tmp_path)
+        cold.solve()
+        real = keys.env_fingerprint
+        monkeypatch.setattr(keys, "env_fingerprint", lambda device=None: real(chip))
+        other = pr_solver(tmp_path)
+        assert other.persist.dir != cold.persist.dir
+        other.solve()
+        assert_cold(other)
+
     def test_corrupt_entries_fall_back_cold(self, tmp_path):
         cold = pr_solver(tmp_path)
         r_cold = cold.solve()
@@ -180,7 +203,7 @@ class TestDeltaReprobing:
             locality=0.0,
             edges=200_000,
             bytes_per_elem=4,
-            hw=TPUCostParams(),
+            hw=DEVICE_COST_PARAMS[PLANNING_TARGET],
         )
 
     def test_refit_flat_observations_push_delta_up(self):
@@ -222,7 +245,7 @@ class TestDeltaReprobing:
             locality=0.0,
             edges=seed.graph.nnz,
             bytes_per_elem=4,
-            hw=TPUCostParams(),
+            hw=DEVICE_COST_PARAMS[PLANNING_TARGET],
         )
         assert base.best_delta() < base.B
         seed.persist.save_delta_model(base, base.best_delta())

@@ -16,7 +16,6 @@ import pytest
 sys.path.insert(0, ".")  # benchmarks is a top-level package in the repo
 from benchmarks.model_costs import cell_cost
 from repro.configs.shapes import ShapeSpec
-from repro.dist.compat import cost_analysis
 from repro.models.config import ModelConfig
 from repro.train.optimizer import AdamW, constant
 from repro.train.train_step import init_train_state, make_train_step
@@ -50,7 +49,7 @@ def test_xla_counts_loop_bodies_once():
     w = jax.ShapeDtypeStruct((128, 128), jnp.float32)
     c = jax.jit(f_scan).lower(x, w).compile()
     one_matmul = 2 * 128**3
-    assert cost_analysis(c)["flops"] < 2 * one_matmul  # not 10×
+    assert c.cost_analysis()["flops"] < 2 * one_matmul  # not 10×
 
 
 def test_train_flops_model_matches_unrolled_compile():
@@ -64,7 +63,7 @@ def test_train_flops_model_matches_unrolled_compile():
     }
     step = make_train_step(CFG, opt)
     compiled = jax.jit(step).lower(state, batch).compile()
-    hlo_flops = cost_analysis(compiled)["flops"]
+    hlo_flops = compiled.cost_analysis()["flops"]
     shape = ShapeSpec("val", "train", S, B)
     model = cell_cost(CFG, shape).flops
     ratio = model / hlo_flops
@@ -80,7 +79,7 @@ def test_prefill_flops_model_matches():
     compiled = (
         jax.jit(lambda p, b: prefill(p, CFG, b)).lower(params, batch).compile()
     )
-    hlo_flops = cost_analysis(compiled)["flops"]
+    hlo_flops = compiled.cost_analysis()["flops"]
     shape = ShapeSpec("val", "prefill", S, B)
     model = cell_cost(CFG, shape).flops
     ratio = model / hlo_flops

@@ -72,7 +72,7 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.graphs.generators import make_graph
 from repro.core.engine import make_schedule, round_fn
 from repro.core.semiring import PLUS_TIMES
-from repro.dist.compat import AxisType, make_mesh, set_mesh
+from repro.dist.compat import make_mesh
 from repro.dist.engine_sharded import sharded_round_fn
 g = make_graph("web", scale=10, efactor=8, kind="pagerank")
 n = g.n; tele = np.float32((1-.85)/n)
@@ -81,9 +81,9 @@ ru = lambda old, red, rows: tele + red
 rnd = jax.jit(round_fn(sched, PLUS_TIMES, ru))
 x0 = jnp.concatenate([jnp.full((n,), 1.0/n, jnp.float32), jnp.zeros((1,), jnp.float32)])
 x_ref = rnd(rnd(x0))
-mesh = make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
+mesh = make_mesh((4,), ("data",))
 srnd = jax.jit(sharded_round_fn(sched, PLUS_TIMES, ru, mesh, axis="data"))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     x_s = srnd(srnd(x0, sched.src, sched.val, sched.dst_local, sched.rows),
                sched.src, sched.val, sched.dst_local, sched.rows)
 assert float(jnp.abs(x_ref - x_s).max()) == 0.0, "sharded != reference"
