@@ -47,6 +47,7 @@ __all__ = [
     "schedule_args",
     "host_loop",
     "execute_solve_fn",
+    "changed_rows",
     "run_host",
     "run_jit",
     "extend_frontier",
@@ -245,31 +246,37 @@ def _commit_step(
     """
     P, delta = sched.P, sched.delta
     feat = x_ext.shape[1:]  # () for vector state, (F,) for matrix state
-    src_s = jax.lax.dynamic_index_in_dim(sched.src, s, 0, keepdims=False)
-    val_s = jax.lax.dynamic_index_in_dim(sched.val, s, 0, keepdims=False)
-    dst_s = jax.lax.dynamic_index_in_dim(sched.dst_local, s, 0, keepdims=False)
-    rows_s = jax.lax.dynamic_index_in_dim(sched.rows, s, 0, keepdims=False)
-
-    gathered = x_ext[src_s]  # (P, M) + feat — reads the committed frontier
-    # Edge values broadcast over the feature axis: one ⊗ weight per edge.
-    val_b = val_s.reshape(val_s.shape + (1,) * len(feat))
-    contrib = semiring.mul(gathered, val_b)  # (P, M) + feat
-    # Per-worker segment-⊕ into δ + 1 slots (last = padding dump).
-    seg = dst_s + (jnp.arange(P, dtype=jnp.int32) * (delta + 1))[:, None]
-    reduced = semiring.segment_reduce(
-        contrib.reshape((-1,) + feat), seg.reshape(-1), P * (delta + 1)
-    ).reshape((P, delta + 1) + feat)[:, :delta]
-    old = x_ext[rows_s]  # (P, delta) + feat
-    if q is None:
-        new = row_update(old, reduced, rows_s)
-    else:
-        new = row_update(old, reduced, rows_s, q)
-    # Publish: the flush.  Padding rows all point at the dump slot (index n).
-    return x_ext.at[rows_s.reshape(-1)].set(
-        new.reshape((-1,) + feat).astype(x_ext.dtype),
-        mode="drop",
-        unique_indices=False,
-    )
+    # Three named scopes, one per kind of work; they reach each op's
+    # ``op_name`` metadata (and so a device trace) and emit no ops.  A fused
+    # kernel is named by its root op, so each scope ends with the reshape that
+    # hands its result on.
+    with jax.named_scope("commit.gather"):
+        src_s = jax.lax.dynamic_index_in_dim(sched.src, s, 0, keepdims=False)
+        val_s = jax.lax.dynamic_index_in_dim(sched.val, s, 0, keepdims=False)
+        dst_s = jax.lax.dynamic_index_in_dim(sched.dst_local, s, 0, keepdims=False)
+        rows_s = jax.lax.dynamic_index_in_dim(sched.rows, s, 0, keepdims=False)
+        gathered = x_ext[src_s]  # (P, M) + feat — reads the committed frontier
+        # Edge values broadcast over the feature axis: one ⊗ weight per edge.
+        val_b = val_s.reshape(val_s.shape + (1,) * len(feat))
+        contrib = semiring.mul(gathered, val_b).reshape((-1,) + feat)  # (P·M,)+feat
+    with jax.named_scope("commit.segment_reduce"):
+        # Per-worker segment-⊕ into δ + 1 slots (last = padding dump).
+        seg = dst_s + (jnp.arange(P, dtype=jnp.int32) * (delta + 1))[:, None]
+        reduced = semiring.segment_reduce(
+            contrib, seg.reshape(-1), P * (delta + 1)
+        ).reshape((P, delta + 1) + feat)[:, :delta]
+    with jax.named_scope("commit.publish"):
+        old = x_ext[rows_s]  # (P, delta) + feat
+        if q is None:
+            new = row_update(old, reduced, rows_s)
+        else:
+            new = row_update(old, reduced, rows_s, q)
+        # Publish: the flush.  Padding rows all point at the dump slot (index n).
+        return x_ext.at[rows_s.reshape(-1)].set(
+            new.reshape((-1,) + feat).astype(x_ext.dtype),
+            mode="drop",
+            unique_indices=False,
+        )
 
 
 def round_fn(sched: DeviceSchedule, semiring: Semiring, row_update) -> Callable:
@@ -370,6 +377,50 @@ def round_fn_q_dyn(sched: DeviceSchedule, semiring: Semiring, row_update) -> Cal
     return body
 
 
+def changed_rows(x_prev, x_new):
+    """Rows of two extended frontiers (dump slot left out) whose value differs.
+
+    A ``uint32`` scalar, exact for ``n < 2**32``; a row of a matrix frontier
+    counts once however many of its features changed.
+    """
+    ne = x_new[:-1] != x_prev[:-1]
+    if ne.ndim > 1:
+        ne = jnp.any(ne, axis=tuple(range(1, ne.ndim)))
+    return jnp.sum(ne, dtype=jnp.uint32)
+
+
+def _fixed_point_loop(rnd, residual_fn, x_ext, tol, max_rounds):
+    """Rounds of ``rnd: x_ext -> x_ext`` on device until ``residual ≤ tol``.
+
+    Returns the ``lax.while_loop`` carry ``(x_ext, residual, rounds,
+    converged, changed)``.  ``changed`` is the running total of
+    :func:`changed_rows` as two ``uint32`` words ``(low, high)``, exact to
+    ``2**64`` without x64: ``n · max_rounds`` passes ``2**32`` at
+    ``n = 2**20`` within 4096 rounds.
+    """
+
+    def cond(carry):
+        _, _, rounds, converged, _ = carry
+        return jnp.logical_and(rounds < max_rounds, jnp.logical_not(converged))
+
+    def body(carry):
+        x, _, rounds, _, changed = carry
+        x_new = rnd(x)
+        res = residual_fn(x[:-1], x_new[:-1]).astype(jnp.float32)
+        low = changed[0] + changed_rows(x, x_new)
+        high = changed[1] + (low < changed[0]).astype(jnp.uint32)
+        return x_new, res, rounds + 1, res <= tol, jnp.stack([low, high])
+
+    init = (
+        x_ext,
+        jnp.asarray(np.inf, jnp.float32),
+        jnp.asarray(0, jnp.int32),
+        jnp.asarray(False),
+        jnp.zeros((2,), jnp.uint32),
+    )
+    return jax.lax.while_loop(cond, body, init)
+
+
 def make_solve_fn_q_dyn(
     sched: DeviceSchedule,
     semiring: Semiring,
@@ -385,23 +436,13 @@ def make_solve_fn_q_dyn(
     rnd = round_fn_q_dyn(sched, semiring, row_update)
 
     def solve_loop(x_ext, q, src, val, dst_local, rows, tol, max_rounds):
-        def cond(carry):
-            _, _, rounds, converged = carry
-            return jnp.logical_and(rounds < max_rounds, jnp.logical_not(converged))
-
-        def body(carry):
-            x, _, rounds, _ = carry
-            x_new = rnd(x, q, src, val, dst_local, rows)
-            res = residual_fn(x[:-1], x_new[:-1]).astype(jnp.float32)
-            return x_new, res, rounds + 1, res <= tol
-
-        init = (
+        return _fixed_point_loop(
+            lambda x: rnd(x, q, src, val, dst_local, rows),
+            residual_fn,
             x_ext,
-            jnp.asarray(np.inf, jnp.float32),
-            jnp.asarray(0, jnp.int32),
-            jnp.asarray(False),
+            tol,
+            max_rounds,
         )
-        return jax.lax.while_loop(cond, body, init)
 
     return solve_loop
 
@@ -417,8 +458,8 @@ def make_solve_fn_q(
 
     The returned function runs rounds until ``residual ≤ tol`` or
     ``max_rounds``, entirely on device (``lax.while_loop``), and returns the
-    carry ``(x_ext, residual, rounds, converged)``.  ``tol``/``max_rounds``
-    are traced arguments, so changing them never retraces.
+    carry of :func:`_fixed_point_loop`.  ``tol``/``max_rounds`` are traced
+    arguments, so changing them never retraces.
 
     ``round_builder`` swaps the round implementation the loop iterates —
     :func:`round_fn_q` (the XLA round) or :func:`round_fn_pallas_q` (the
@@ -428,23 +469,9 @@ def make_solve_fn_q(
     rnd = round_builder(sched, semiring, row_update)
 
     def solve_loop(x_ext, q, tol, max_rounds):
-        def cond(carry):
-            _, _, rounds, converged = carry
-            return jnp.logical_and(rounds < max_rounds, jnp.logical_not(converged))
-
-        def body(carry):
-            x, _, rounds, _ = carry
-            x_new = rnd(x, q)
-            res = residual_fn(x[:-1], x_new[:-1]).astype(jnp.float32)
-            return x_new, res, rounds + 1, res <= tol
-
-        init = (
-            x_ext,
-            jnp.asarray(np.inf, jnp.float32),
-            jnp.asarray(0, jnp.int32),
-            jnp.asarray(False),
+        return _fixed_point_loop(
+            lambda x: rnd(x, q), residual_fn, x_ext, tol, max_rounds
         )
-        return jax.lax.while_loop(cond, body, init)
 
     return solve_loop
 
@@ -484,6 +511,7 @@ class EngineResult:
     P: int
     compile_time_s: float = 0.0  # trace+compile cost paid by THIS run (0 = warm)
     total_time_s: float = 0.0  # device execution wall time, compile excluded
+    changed_rows: int | None = None  # Σ over rounds of rows whose value changed
 
     @property
     def avg_round_time_s(self) -> float:
@@ -504,6 +532,7 @@ class EngineResult:
         round_times_s: list,
         compile_time_s: float = 0.0,
         total_time_s: float | None = None,
+        changed_rows: int | None = None,
     ) -> "EngineResult":
         """Single authority for counter/timing semantics across every runner.
 
@@ -535,6 +564,7 @@ class EngineResult:
             P=sched.P,
             compile_time_s=compile_time_s,
             total_time_s=total_time_s,
+            changed_rows=changed_rows,
         )
 
 
@@ -588,7 +618,7 @@ def host_loop(
     """
     residuals, times = [], []
     converged = False
-    rounds = 0
+    rounds = changed = 0
     for rounds in range(1, max_rounds + 1):
         # chaos hook at the natural recovery boundary: between committed
         # rounds, with `round` = rounds already executed (0-based)
@@ -597,8 +627,12 @@ def host_loop(
         x_new = rnd(x_ext)
         x_new.block_until_ready()
         times.append(time.perf_counter() - t0)
-        res = float(residual_fn(x_ext[:-1], x_new[:-1]))
+        res, c = jax.device_get(
+            (residual_fn(x_ext[:-1], x_new[:-1]), changed_rows(x_ext, x_new))
+        )
+        res = float(res)
         residuals.append(res)
+        changed += int(c)
         x_ext = x_new
         if res <= tol:
             converged = True
@@ -612,6 +646,7 @@ def host_loop(
         residuals=residuals,
         round_times_s=times,
         compile_time_s=compile_time_s,
+        changed_rows=changed,
     )
 
 
@@ -635,9 +670,10 @@ def execute_solve_fn(
     mr_a = jnp.asarray(max_rounds, jnp.int32)
     args = (x_ext, tol_a, mr_a) if q is None else (x_ext, q, tol_a, mr_a)
     t0 = time.perf_counter()
-    x_out, res, rounds, converged = fn(*args)
+    x_out, *scalars = fn(*args)
     x_out.block_until_ready()
     total_time_s = time.perf_counter() - t0
+    res, rounds, converged, (low, high) = jax.device_get(scalars)
     return EngineResult.from_run(
         sched,
         semiring,
@@ -648,6 +684,7 @@ def execute_solve_fn(
         round_times_s=[],
         compile_time_s=compile_time_s,
         total_time_s=total_time_s,
+        changed_rows=int(low) + (int(high) << 32),
     )
 
 

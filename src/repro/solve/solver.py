@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core.delta_model import fit_delta_model, refit_delta_models
 from repro.core.engine import (
     MIN_CHUNK,
@@ -86,6 +87,7 @@ HALO_DTYPES = ("f32", "int8", "fp8")
 _FUSED_ROUND_BUILDERS = {"jit": round_fn_q, "pallas": round_fn_pallas_q}
 
 _NO_QUERY = np.zeros((), dtype=np.int32)  # dummy q for query-free problems
+_STRIPES = ("src", "val", "dst_local", "rows")  # the schedule arrays placed on device
 
 
 class Solver:
@@ -189,8 +191,6 @@ class Solver:
             "plan_builds": 0,
             "stripe_builds": 0,
             "stripe_loads": 0,
-            "plan_shard_builds": 0,
-            "plan_shard_loads": 0,
             "traces": 0,
             "compiles": 0,
             "compile_time_s": 0.0,
@@ -444,35 +444,50 @@ class Solver:
         ``schedule_builds`` counts schedules with ≥ 1 cold stripe, preserving
         the warm-start gate's "zero builds" meaning; ``stripe_builds`` /
         ``stripe_loads`` break the same event down per worker.
+
+        A miss is the span ``repro.schedule.build``: the schedule is made on
+        the host, then placed on the device in its child span
+        ``repro.schedule.put``, which ends once the stripes are there.
         """
         delta_eff = self.resolve_delta(delta)
         sched = self._schedules.get(delta_eff)
-        if sched is None and self.persist is not None:
-            sched = self.persist.load_schedule(delta_eff, put=self._schedule_put())
-            if sched is not None:
-                self._schedules[delta_eff] = sched
-                self.stats["cache_loads"] += 1
-        if sched is None and self.persist is not None:
-            sched = self._schedule_from_stripes(delta_eff)
-        if sched is None:
-            sched = make_schedule(
-                self._sched_graph,
-                self.n_workers,
-                delta_eff,
-                self.problem.semiring,
-                mode="delayed",
-                min_chunk=self.min_chunk,
-                bounds=self.bounds,
-                put=self._schedule_put(),
-            )
-            self._schedules[delta_eff] = sched
-            self.stats["schedule_builds"] += 1
+        if sched is not None:
+            return sched
+        with spans.span("repro.schedule.build"):
             if self.persist is not None:
-                self.persist.save_schedule(sched)
+                sched = self.persist.load_schedule(delta_eff, put=np.asarray)
+                if sched is not None:
+                    self.stats["cache_loads"] += 1
+                else:
+                    sched = self._schedule_from_stripes(delta_eff)
+            if sched is None:
+                sched = make_schedule(
+                    self._sched_graph,
+                    self.n_workers,
+                    delta_eff,
+                    self.problem.semiring,
+                    mode="delayed",
+                    min_chunk=self.min_chunk,
+                    bounds=self.bounds,
+                    put=np.asarray,
+                )
+                self.stats["schedule_builds"] += 1
+                if self.persist is not None:
+                    self.persist.save_schedule(sched)
+            with spans.span("repro.schedule.put"):
+                put = self._schedule_put()
+                sched = dataclasses.replace(
+                    sched, **{k: put(getattr(sched, k)) for k in _STRIPES}
+                )
+                jax.block_until_ready(schedule_args(sched))
+        self._schedules[delta_eff] = sched
         return sched
 
     def _schedule_from_stripes(self, delta_eff: int) -> DeviceSchedule:
-        """Assemble the schedule stripe-by-stripe through the shared store."""
+        """Assemble the schedule stripe-by-stripe through the shared store.
+
+        The schedule is returned on the host, for :meth:`schedule` to place.
+        """
         from repro.persist.keys import stripe_fingerprint
 
         bounds = self.bounds
@@ -500,8 +515,7 @@ class Solver:
         host = assemble_stripe_schedule(
             self._sched_graph, bounds, delta_eff, pad_val, stripes
         )
-        sched = DeviceSchedule.from_stripes(host, self._schedule_put())
-        self._schedules[delta_eff] = sched
+        sched = DeviceSchedule.from_stripes(host, np.asarray)
         if built:
             self.stats["schedule_builds"] += 1
         else:
@@ -571,10 +585,7 @@ class Solver:
                         sched, int(vb[d]), int(vb[d + 1]), w0, w1
                     )
                     self.persist.save_plan_shard(digest, piece)
-                    self.stats["plan_shard_builds"] += 1
                     built += 1
-                else:
-                    self.stats["plan_shard_loads"] += 1
                 pieces.append(piece)
             plan = assemble_frontier_plan(sched, D, pieces)
             if built:
@@ -721,50 +732,58 @@ class Solver:
         ``self.degradations``.  Because every backend computes bit-identical
         rounds, a degraded solve returns the same answer, only slower.
         """
-        backend = backend or self.default_backend
-        if backend not in BACKENDS:
-            raise ValueError(f"backend must be one of {BACKENDS}, got {backend!r}")
-        frontier = self.resolve_frontier(frontier, backend)
-        halo_dtype = self.resolve_halo_dtype(halo_dtype, backend, frontier)
-        tol = self.tol if tol is None else tol
-        max_rounds = self.max_rounds if max_rounds is None else max_rounds
-        sched = self.schedule(delta)
-        x_ext = self._x_ext(x0)
-        q = self.resolve_query(q)
-        self.stats["solves"] += 1
-        attempts = (
-            degradation_ladder(backend, frontier)
-            if self.degrade
-            else [(backend, frontier)]
-        )
-        result = None
-        for rung, (b, f) in enumerate(attempts):
-            hd = halo_dtype if rung == 0 else self.resolve_halo_dtype(None, b, f)
-            try:
-                result = self._solve_once(b, f, hd, sched, x_ext, q, tol, max_rounds)
-                break
-            except (ValueError, TypeError):
-                raise  # caller errors — never mask these behind a fallback
-            except Exception as err:
-                if rung + 1 == len(attempts):
-                    raise
-                nb, nf = attempts[rung + 1]
-                self.degradations.append(
-                    Degradation(
-                        site="solve",
-                        from_backend=b,
-                        from_frontier=f,
-                        to_backend=nb,
-                        to_frontier=nf,
-                        error=repr(err),
-                        rung=rung + 1,
+        with spans.span("repro.solve") as attrs:
+            backend = backend or self.default_backend
+            if backend not in BACKENDS:
+                raise ValueError(f"backend must be one of {BACKENDS}, got {backend!r}")
+            frontier = self.resolve_frontier(frontier, backend)
+            halo_dtype = self.resolve_halo_dtype(halo_dtype, backend, frontier)
+            tol = self.tol if tol is None else tol
+            max_rounds = self.max_rounds if max_rounds is None else max_rounds
+            sched = self.schedule(delta)
+            x_ext = self._x_ext(x0)
+            q = self.resolve_query(q)
+            self.stats["solves"] += 1
+            attempts = (
+                degradation_ladder(backend, frontier)
+                if self.degrade
+                else [(backend, frontier)]
+            )
+            result = None
+            for rung, (b, f) in enumerate(attempts):
+                hd = halo_dtype if rung == 0 else self.resolve_halo_dtype(None, b, f)
+                try:
+                    result = self._solve_once(
+                        b, f, hd, sched, x_ext, q, tol, max_rounds
                     )
-                )
-                self.stats["degradations"] += 1
-        self._last_x = np.asarray(result.x)
-        self._record_observation(
-            sched.delta, result.rounds, result.total_time_s, backend, regime=regime
-        )
+                    break
+                except (ValueError, TypeError):
+                    raise  # caller errors — never mask these behind a fallback
+                except Exception as err:
+                    if rung + 1 == len(attempts):
+                        raise
+                    nb, nf = attempts[rung + 1]
+                    self.degradations.append(
+                        Degradation(
+                            site="solve",
+                            from_backend=b,
+                            from_frontier=f,
+                            to_backend=nb,
+                            to_frontier=nf,
+                            error=repr(err),
+                            rung=rung + 1,
+                        )
+                    )
+                    self.stats["degradations"] += 1
+            self._last_x = np.asarray(result.x)
+            self._record_observation(
+                sched.delta, result.rounds, result.total_time_s, backend, regime=regime
+            )
+            attrs.update(
+                rounds=result.rounds,
+                changed_rows=result.changed_rows,
+                row_updates=result.rounds * self.graph.n,
+            )
         return result
 
     def _solve_once(
