@@ -31,7 +31,12 @@ import numpy as np
 
 from benchmarks.common import write_json_atomic
 
-from repro.core.engine import _commit_step, make_schedule, round_fn_pallas
+from repro.core.engine import (
+    _commit_step,
+    make_schedule,
+    round_fn_pallas,
+    schedule_args,
+)
 from repro.core.semiring import PLUS_TIMES
 from repro.dist.compat import make_mesh
 from repro.dist.engine_sharded import input_specs_for_engine, sharded_round_fn
@@ -65,14 +70,16 @@ def fused_vs_xla_round_bytes(sched, row_update) -> dict:
       as the measured upper line.
     """
     x_ext = jax.ShapeDtypeStruct((sched.n_slots,), PLUS_TIMES.dtype)
-    stripes = (sched.src, sched.val, sched.dst_local, sched.rows)
+    stripes = schedule_args(sched)
     stripe_avals = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in stripes)
 
     # The stripe arrays are explicit arguments on both sides (rather than
     # compiled-in constants) so both measurements count the edge traffic.
     def with_stripes(fn_of_sched):
-        def wrapped(x, src, val, dst, rows):
-            s = dataclasses.replace(sched, src=src, val=val, dst_local=dst, rows=rows)
+        def wrapped(x, src, val, dst, rows, row_last):
+            s = dataclasses.replace(
+                sched, src=src, val=val, dst_local=dst, rows=rows, row_last=row_last
+            )
             return fn_of_sched(s)(x)
 
         return jax.jit(wrapped).lower(x_ext, *stripe_avals).compile()

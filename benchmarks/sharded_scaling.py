@@ -30,8 +30,8 @@ import numpy as np
 
 from benchmarks.common import write_json_atomic
 
-from repro.core.engine import make_schedule
-from repro.core.semiring import PLUS_TIMES
+from repro.core.engine import make_schedule, schedule_args
+from repro.core.semiring import PLUS_TIMES, edge_products, sorted_segment_reduce
 from repro.dist.compat import make_mesh
 from repro.dist.engine_sharded import (
     frontier_ef_init,
@@ -88,23 +88,29 @@ def fused_halo_step_gate(sched, plan, row_update_q) -> dict:
         jax.ShapeDtypeStruct((P_loc, M), jnp.int32),
         jax.ShapeDtypeStruct((P_loc, delta), jnp.int32),
         jax.ShapeDtypeStruct((P_loc, delta), jnp.int32),
+        jax.ShapeDtypeStruct((P_loc, delta), jnp.int32),
         jax.ShapeDtypeStruct((H,), jnp.int32),
         jax.ShapeDtypeStruct((), jnp.int32),
     )
+    passes = sched.passes
     step = fused_halo_step_fn(
-        PLUS_TIMES, row_update_q, P_loc=P_loc, M=M, delta=delta, L=L, H=H
+        PLUS_TIMES,
+        row_update_q,
+        P_loc=P_loc,
+        M=M,
+        delta=delta,
+        L=L,
+        H=H,
+        passes=passes,
     )
     mem = jax.jit(step).lower(*avals).compile().memory_analysis()
     pallas_step = float(mem.argument_size_in_bytes + mem.output_size_in_bytes)
 
-    def xla_step(x, src_s, val_s, dst_s, rg_s, rl_s, snd_s, q):
+    def xla_step(x, src_s, val_s, dst_s, last_s, rg_s, rl_s, snd_s, q):
         # one commit step of frontier_sharded_round_fn's body, collectives
         # excluded on both sides (the wire is gated separately below)
-        contrib = PLUS_TIMES.mul(x[src_s], val_s)
-        seg = dst_s + (jnp.arange(P_loc, dtype=jnp.int32) * (delta + 1))[:, None]
-        reduced = PLUS_TIMES.segment_reduce(
-            contrib.reshape(-1), seg.reshape(-1), P_loc * (delta + 1)
-        ).reshape(P_loc, delta + 1)[:, :delta]
+        contrib = edge_products(PLUS_TIMES, x[src_s], val_s, dst_s)
+        reduced = sorted_segment_reduce(PLUS_TIMES, contrib, dst_s, last_s, passes)
         new = row_update_q(x[rl_s], reduced, rg_s, q)
         newv = new.reshape(-1).astype(x.dtype)
         x = x.at[rl_s.reshape(-1)].set(newv, mode="drop", unique_indices=False)
@@ -137,6 +143,7 @@ def quantized_wire_gate(sched, plan, mesh, row_update_q, x_loc) -> dict:
         sched.val,
         sched.dst_local,
         sched.rows,
+        sched.row_last,
         plan.rows_loc,
         plan.send_idx,
         plan.recv_idx,
@@ -216,6 +223,7 @@ def main(argv=None):
             sched.val,
             sched.dst_local,
             sched.rows,
+            sched.row_last,
             plan.rows_loc,
             plan.send_idx,
             plan.recv_idx,
@@ -242,7 +250,7 @@ def main(argv=None):
                 quantized_wire_gate(sched, plan, mesh, row_update_q, halo_args[0])
             )
         if args.timed:
-            rep_args = (x_ext, sched.src, sched.val, sched.dst_local, sched.rows)
+            rep_args = (x_ext, *schedule_args(sched))
             row["replicated_round_s"] = _timed_round(rep_c, rep_args)
             row["halo_round_s"] = _timed_round(halo_c, halo_args)
         rows.append(row)

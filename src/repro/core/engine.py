@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.semiring import Semiring
+from repro.core.semiring import Semiring, edge_products, sorted_segment_reduce
 from repro.ft.inject import fire
 from repro.graphs.formats import CSRGraph, StripeSchedule, build_stripe_schedule
 from repro.graphs.partition import balanced_blocks
@@ -91,8 +91,13 @@ class DeviceSchedule:
     val: jnp.ndarray  # (S, P, M)
     dst_local: jnp.ndarray  # (S, P, M) int32
     rows: jnp.ndarray  # (S, P, delta) int32
+    row_last: jnp.ndarray  # (S, P, delta) int32, -1 for a row with no edges
     edges: int
     padding_overhead: float
+    # Doubling passes of the sorted segment-⊕, ⌈log₂ longest_row⌉ when built;
+    # static, like M: a patch whose row outgrows 2**passes drops the schedule.
+    passes: int
+    longest_row: int  # most edges of any one row (the largest in-degree)
     block_bounds: np.ndarray | None = None  # (P + 1,) int64 host-side bounds
 
     @property
@@ -132,8 +137,11 @@ class DeviceSchedule:
             "val": np.asarray(self.val),
             "dst_local": np.asarray(self.dst_local),
             "rows": np.asarray(self.rows),
+            "row_last": np.asarray(self.row_last),
             "edges": np.int64(self.edges),
             "padding_overhead": np.float64(self.padding_overhead),
+            "passes": np.int64(self.passes),
+            "longest_row": np.int64(self.longest_row),
             "block_bounds": np.asarray(
                 self.block_bounds if self.block_bounds is not None else []
             ),
@@ -152,8 +160,11 @@ class DeviceSchedule:
             val=put(host.val),
             dst_local=put(host.dst_local),
             rows=put(host.rows),
+            row_last=put(host.row_last),
             edges=host.edges,
             padding_overhead=host.padding_overhead,
+            passes=host.passes,
+            longest_row=host.longest_row,
             block_bounds=np.asarray(host.block_bounds),
         )
 
@@ -166,12 +177,14 @@ class DeviceSchedule:
         val = np.asarray(arrays["val"])
         dst_local = np.asarray(arrays["dst_local"])
         rows = np.asarray(arrays["rows"])
+        row_last = np.asarray(arrays["row_last"])
         bb = np.asarray(arrays["block_bounds"])
         if (
             src.shape != (S, P, M)
             or val.shape != (S, P, M)
             or dst_local.shape != (S, P, M)
             or rows.shape != (S, P, delta)
+            or row_last.shape != (S, P, delta)
         ):
             raise ValueError("schedule arrays inconsistent with (S, P, M, delta)")
         return cls(
@@ -184,8 +197,11 @@ class DeviceSchedule:
             val=put(val),
             dst_local=put(dst_local),
             rows=put(rows),
+            row_last=put(row_last),
             edges=int(arrays["edges"]),
             padding_overhead=float(arrays["padding_overhead"]),
+            passes=int(arrays["passes"]),
+            longest_row=int(arrays["longest_row"]),
             block_bounds=bb.astype(np.int64) if bb.size else None,
         )
 
@@ -240,11 +256,7 @@ def _commit_step(
 
     Shape-generic over the frontier's trailing feature axes: ``x_ext`` may be
     ``(n+1,)`` (the classic vector engine) or ``(n+1, F)`` (matrix frontiers).
-    For the vector case every reshape below is the identity, so the emitted
-    computation — and therefore the result — is bit-identical to the
-    historical vector-only commit step.
     """
-    P, delta = sched.P, sched.delta
     feat = x_ext.shape[1:]  # () for vector state, (F,) for matrix state
     # Three named scopes, one per kind of work; they reach each op's
     # ``op_name`` metadata (and so a device trace) and emit no ops.  A fused
@@ -254,17 +266,15 @@ def _commit_step(
         src_s = jax.lax.dynamic_index_in_dim(sched.src, s, 0, keepdims=False)
         val_s = jax.lax.dynamic_index_in_dim(sched.val, s, 0, keepdims=False)
         dst_s = jax.lax.dynamic_index_in_dim(sched.dst_local, s, 0, keepdims=False)
+        last_s = jax.lax.dynamic_index_in_dim(sched.row_last, s, 0, keepdims=False)
         rows_s = jax.lax.dynamic_index_in_dim(sched.rows, s, 0, keepdims=False)
         gathered = x_ext[src_s]  # (P, M) + feat — reads the committed frontier
-        # Edge values broadcast over the feature axis: one ⊗ weight per edge.
-        val_b = val_s.reshape(val_s.shape + (1,) * len(feat))
-        contrib = semiring.mul(gathered, val_b).reshape((-1,) + feat)  # (P·M,)+feat
+        contrib = edge_products(semiring, gathered, val_s, dst_s)
     with jax.named_scope("commit.segment_reduce"):
-        # Per-worker segment-⊕ into δ + 1 slots (last = padding dump).
-        seg = dst_s + (jnp.arange(P, dtype=jnp.int32) * (delta + 1))[:, None]
-        reduced = semiring.segment_reduce(
-            contrib, seg.reshape(-1), P * (delta + 1)
-        ).reshape((P, delta + 1) + feat)[:, :delta]
+        # Per-worker ⊕ over each cell row's slots (padding rides in row δ).
+        reduced = sorted_segment_reduce(
+            semiring, contrib, dst_s, last_s, sched.passes
+        )
     with jax.named_scope("commit.publish"):
         old = x_ext[rows_s]  # (P, delta) + feat
         if q is None:
@@ -345,29 +355,29 @@ def schedule_args(sched: DeviceSchedule) -> tuple:
     """The schedule's *data* arrays, in :func:`round_fn_q_dyn` argument order.
 
     Everything else on a :class:`DeviceSchedule` — ``n``, ``P``, ``delta``,
-    ``S``, ``M`` — is shape metadata that must stay static for the compiled
-    round; these four arrays are the edge content that an
+    ``S``, ``M``, ``passes`` — is metadata that must stay static for the
+    compiled round; these five arrays are the edge content that an
     :class:`repro.graphs.updates.EdgeBatch` can change without changing
     shapes, so the dynamic round takes them as traced inputs.
     """
-    return sched.src, sched.val, sched.dst_local, sched.rows
+    return sched.src, sched.val, sched.dst_local, sched.rows, sched.row_last
 
 
 def round_fn_q_dyn(sched: DeviceSchedule, semiring: Semiring, row_update) -> Callable:
-    """``(x_ext, q, src, val, dst_local, rows) -> x_ext``: schedule-as-data round.
+    """``(x_ext, q, *schedule_args) -> x_ext``: schedule-as-data round.
 
     Same commit-step semantics as :func:`round_fn_q`, but the schedule arrays
     arrive as traced arguments instead of closure constants — ``sched`` only
-    pins the static shape metadata ``(S, P, M, delta, n)``.  This is the
+    pins the static metadata ``(S, P, M, delta, n, passes)``.  This is the
     evolving-graph hot path: after ``Solver.apply_updates`` patches a
     schedule's stripes in place, the same compiled executable replays with the
     new arrays (mirroring how ``sharded_round_fn_q`` already treats its plan),
     so small edge batches never pay a retrace.
     """
 
-    def body(x_ext, q, src, val, dst_local, rows):
+    def body(x_ext, q, src, val, dst_local, rows, row_last):
         dyn = dataclasses.replace(
-            sched, src=src, val=val, dst_local=dst_local, rows=rows
+            sched, src=src, val=val, dst_local=dst_local, rows=rows, row_last=row_last
         )
         step = partial(
             _commit_step, sched=dyn, semiring=semiring, row_update=row_update, q=q
@@ -427,17 +437,17 @@ def make_solve_fn_q_dyn(
     row_update,
     residual_fn,
 ) -> Callable:
-    """``(x_ext, q, src, val, dst_local, rows, tol, max_rounds) -> carry``.
+    """``(x_ext, q, *schedule_args, tol, max_rounds) -> carry``.
 
     The fused while-loop of :func:`make_solve_fn_q` over the dynamic round:
-    one compiled executable per ``(S, P, M, delta)`` shape class serves every
-    same-shape mutation of the graph.
+    one compiled executable per ``(S, P, M, delta, passes)`` shape class
+    serves every same-shape mutation of the graph.
     """
     rnd = round_fn_q_dyn(sched, semiring, row_update)
 
-    def solve_loop(x_ext, q, src, val, dst_local, rows, tol, max_rounds):
+    def solve_loop(x_ext, q, src, val, dst_local, rows, row_last, tol, max_rounds):
         return _fixed_point_loop(
-            lambda x: rnd(x, q, src, val, dst_local, rows),
+            lambda x: rnd(x, q, src, val, dst_local, rows, row_last),
             residual_fn,
             x_ext,
             tol,

@@ -52,7 +52,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.engine import DeviceSchedule
-from repro.core.semiring import Semiring
+from repro.core.semiring import Semiring, edge_products, sorted_segment_reduce
 from repro.dist.compat import mesh_axis_sizes
 from repro.kernels.round_block import fused_halo_step_fn
 
@@ -116,7 +116,7 @@ def sharded_round_fn_q(
     axis: str = "data",
     feature_dims: int = 0,
 ) -> Callable:
-    """Return jit-able ``(x_ext, src, val, dst_local, rows, q) -> x_ext``.
+    """Return jit-able ``(x_ext, src, val, dst_local, rows, row_last, q) -> x_ext``.
 
     One full round (``S`` commit steps) with the worker dimension of the
     schedule sharded over mesh ``axis``; ``x_ext`` and the per-query params
@@ -132,10 +132,9 @@ def sharded_round_fn_q(
     axis_size = mesh_axis_sizes(mesh)[axis]
     if sched.P % axis_size != 0:
         raise ValueError(f"P={sched.P} not divisible by |{axis}|={axis_size}")
-    delta = sched.delta
+    passes = sched.passes
 
-    def body(x_ext, src, val, dst_local, rows, q):
-        P_loc = src.shape[1]
+    def body(x_ext, src, val, dst_local, rows, row_last, q):
         feat = x_ext.shape[1:]
 
         def commit_step(s, x):
@@ -143,14 +142,11 @@ def sharded_round_fn_q(
             val_s = jax.lax.dynamic_index_in_dim(val, s, 0, keepdims=False)
             dst_s = jax.lax.dynamic_index_in_dim(dst_local, s, 0, keepdims=False)
             rows_s = jax.lax.dynamic_index_in_dim(rows, s, 0, keepdims=False)
+            last_s = jax.lax.dynamic_index_in_dim(row_last, s, 0, keepdims=False)
 
             gathered = x[src_s]  # (P_loc, M) + feat — committed frontier reads
-            val_b = val_s.reshape(val_s.shape + (1,) * len(feat))
-            contrib = semiring.mul(gathered, val_b)
-            seg = dst_s + (jnp.arange(P_loc, dtype=jnp.int32) * (delta + 1))[:, None]
-            reduced = semiring.segment_reduce(
-                contrib.reshape((-1,) + feat), seg.reshape(-1), P_loc * (delta + 1)
-            ).reshape((P_loc, delta + 1) + feat)[:, :delta]
+            contrib = edge_products(semiring, gathered, val_s, dst_s)
+            reduced = sorted_segment_reduce(semiring, contrib, dst_s, last_s, passes)
             old = x[rows_s]
             new = row_update(old, reduced, rows_s, q)
             # Flush: gather every worker's chunk, publish with the reference
@@ -170,7 +166,7 @@ def sharded_round_fn_q(
     return jax.shard_map(
         body,
         mesh=mesh,
-        in_specs=(x_spec, sched_spec, sched_spec, sched_spec, sched_spec, P()),
+        in_specs=(x_spec,) + (sched_spec,) * 5 + (P(),),
         out_specs=x_spec,
         check_vma=False,
     )
@@ -184,7 +180,7 @@ def sharded_round_fn(
     axis: str = "data",
     feature_dims: int = 0,
 ) -> Callable:
-    """Query-free surface: ``(x_ext, src, val, dst_local, rows) -> x_ext``.
+    """Query-free surface: ``(x_ext, src, val, dst_local, rows, row_last) -> x_ext``.
 
     ``row_update`` is the 3-arg form ``(old, reduced, rows) -> new``.
     """
@@ -197,8 +193,10 @@ def sharded_round_fn(
         feature_dims,
     )
 
-    def fn(x_ext, src, val, dst_local, rows):
-        return fn_q(x_ext, src, val, dst_local, rows, jnp.zeros((), jnp.int32))
+    def fn(x_ext, src, val, dst_local, rows, row_last):
+        return fn_q(
+            x_ext, src, val, dst_local, rows, row_last, jnp.zeros((), jnp.int32)
+        )
 
     return fn
 
@@ -212,6 +210,7 @@ def input_specs_for_engine(sched: DeviceSchedule, semiring: Semiring) -> tuple:
         SDS(sched.val.shape, sched.val.dtype),
         SDS(sched.dst_local.shape, jnp.int32),
         SDS(sched.rows.shape, jnp.int32),
+        SDS(sched.row_last.shape, jnp.int32),
     )
 
 
@@ -524,8 +523,8 @@ def frontier_sharded_round_fn(
     """Owner-computes round over the sharded frontier ``(D, L)``.
 
     Returns jit-able
-    ``(x_loc, src_loc, val, dst_local, rows, rows_loc, send_idx, recv_idx, q)
-    -> x_loc`` where ``x_loc`` is the stacked per-shard frontier and
+    ``(x_loc, src_loc, val, dst_local, rows, row_last, rows_loc, send_idx,
+    recv_idx, q) -> x_loc`` where ``x_loc`` is the stacked per-shard frontier and
     ``row_update`` is the 4-arg query form.  Each commit step publishes the
     shard's own chunk locally, then all-gathers only the ``(D, H)`` boundary
     entries — O(boundary) wire instead of the replicated O(P·δ).
@@ -537,13 +536,14 @@ def frontier_sharded_round_fn(
     axis_size = mesh_axis_sizes(mesh)[axis]
     if axis_size != plan.D:
         raise ValueError(f"plan built for D={plan.D}, mesh axis |{axis}|={axis_size}")
-    delta, S = sched.delta, sched.S
+    S, passes = sched.S, sched.passes
 
-    def body(x, src_loc, val, dst_local, rows_g, rows_loc, send_idx, recv_idx, q):
+    def body(
+        x, src_loc, val, dst_local, rows_g, row_last, rows_loc, send_idx, recv_idx, q
+    ):
         # Per-shard blocks: x (1, L)+feat; plan blocks (1, S, P_loc, ·);
         # schedule cells (S, P_loc, ·); send (S, 1, H); recv (S, 1, D·H).
         sl, rl = src_loc[0], rows_loc[0]
-        P_loc = sl.shape[1]
         feat = x.shape[2:]
 
         def commit_step(s, xv):
@@ -551,17 +551,14 @@ def frontier_sharded_round_fn(
             val_s = jax.lax.dynamic_index_in_dim(val, s, 0, keepdims=False)
             dst_s = jax.lax.dynamic_index_in_dim(dst_local, s, 0, keepdims=False)
             rg_s = jax.lax.dynamic_index_in_dim(rows_g, s, 0, keepdims=False)
+            last_s = jax.lax.dynamic_index_in_dim(row_last, s, 0, keepdims=False)
             rl_s = jax.lax.dynamic_index_in_dim(rl, s, 0, keepdims=False)
             snd_s = jax.lax.dynamic_index_in_dim(send_idx, s, 0, keepdims=False)[0]
             rcv_s = jax.lax.dynamic_index_in_dim(recv_idx, s, 0, keepdims=False)[0]
 
             gathered = xv[src_s]  # (P_loc, M)+feat — owned + halo reads, local
-            val_b = val_s.reshape(val_s.shape + (1,) * len(feat))
-            contrib = semiring.mul(gathered, val_b)
-            seg = dst_s + (jnp.arange(P_loc, dtype=jnp.int32) * (delta + 1))[:, None]
-            reduced = semiring.segment_reduce(
-                contrib.reshape((-1,) + feat), seg.reshape(-1), P_loc * (delta + 1)
-            ).reshape((P_loc, delta + 1) + feat)[:, :delta]
+            contrib = edge_products(semiring, gathered, val_s, dst_s)
+            reduced = sorted_segment_reduce(semiring, contrib, dst_s, last_s, passes)
             old = xv[rl_s]
             new = row_update(old, reduced, rg_s, q)
             newv = new.reshape((-1,) + feat).astype(xv.dtype)
@@ -581,7 +578,7 @@ def frontier_sharded_round_fn(
     return jax.shard_map(
         body,
         mesh=mesh,
-        in_specs=(x_spec, block, cell, cell, cell, block, cell, cell, P()),
+        in_specs=(x_spec, block, cell, cell, cell, cell, block, cell, cell, P()),
         out_specs=x_spec,
         check_vma=False,
     )
@@ -609,11 +606,24 @@ def frontier_round_ext_fn(
     )
 
     def fn(
-        x_ext, q, src_loc, val, dst_local, rows_g, rows_loc, send, recv, gidx, oflat
+        x_ext,
+        q,
+        src_loc,
+        val,
+        dst_local,
+        rows_g,
+        row_last,
+        rows_loc,
+        send,
+        recv,
+        gidx,
+        oflat,
     ):
         feat = x_ext.shape[1:]
         x_loc = x_ext[gidx]
-        x_out = rnd(x_loc, src_loc, val, dst_local, rows_g, rows_loc, send, recv, q)
+        x_out = rnd(
+            x_loc, src_loc, val, dst_local, rows_g, row_last, rows_loc, send, recv, q
+        )
         owned = x_out.reshape((-1,) + feat)[oflat]
         return jnp.concatenate([owned, x_ext[-1:]])
 
@@ -648,8 +658,8 @@ def frontier_pallas_round_fn(
     """Fused owner-computes round: one Pallas kernel per commit per shard.
 
     Returns jit-able
-    ``(x_loc, ef, src_loc, val, dst_local, rows, rows_loc, send_idx, recv_idx,
-    q) -> (x_loc, ef)``.  Identical exchange discipline to
+    ``(x_loc, ef, src_loc, val, dst_local, rows, row_last, rows_loc, send_idx,
+    recv_idx, q) -> (x_loc, ef)``.  Identical exchange discipline to
     :func:`frontier_sharded_round_fn`, but each shard's commit step —
     gather/⊗/segment-⊕/row-update/publish plus boundary-row selection — runs
     as a single :func:`repro.kernels.round_block.fused_halo_step_fn` kernel
@@ -684,10 +694,23 @@ def frontier_pallas_round_fn(
         delta=sched.delta,
         L=plan.L,
         H=H,
+        passes=sched.passes,
         interpret=interpret,
     )
 
-    def body(x, ef, src_loc, val, dst_local, rows_g, rows_loc, send_idx, recv_idx, q):
+    def body(
+        x,
+        ef,
+        src_loc,
+        val,
+        dst_local,
+        rows_g,
+        row_last,
+        rows_loc,
+        send_idx,
+        recv_idx,
+        q,
+    ):
         # Per-shard blocks: x (1, L); ef (1, S, H); plan blocks
         # (1, S, P_loc, ·); schedule cells (S, P_loc, ·); send (S, 1, H);
         # recv (S, 1, D·H).
@@ -699,13 +722,14 @@ def frontier_pallas_round_fn(
             val_s = jax.lax.dynamic_index_in_dim(val, s, 0, keepdims=False)
             dst_s = jax.lax.dynamic_index_in_dim(dst_local, s, 0, keepdims=False)
             rg_s = jax.lax.dynamic_index_in_dim(rows_g, s, 0, keepdims=False)
+            last_s = jax.lax.dynamic_index_in_dim(row_last, s, 0, keepdims=False)
             rl_s = jax.lax.dynamic_index_in_dim(rl, s, 0, keepdims=False)
             snd_s = jax.lax.dynamic_index_in_dim(send_idx, s, 0, keepdims=False)[0]
             rcv_s = jax.lax.dynamic_index_in_dim(recv_idx, s, 0, keepdims=False)[0]
 
             # Fused commit: publish locally, select boundary rows, in-place
             # on the VMEM-resident frontier slice.
-            xv, send = step(xv, src_s, val_s, dst_s, rg_s, rl_s, snd_s, q)
+            xv, send = step(xv, src_s, val_s, dst_s, last_s, rg_s, rl_s, snd_s, q)
 
             if qinfo is None:
                 buf = jax.lax.all_gather(send, axis, axis=0, tiled=True)
@@ -750,6 +774,7 @@ def frontier_pallas_round_fn(
             x_spec,
             ef_spec,
             block,
+            cell,
             cell,
             cell,
             cell,
@@ -801,6 +826,7 @@ def frontier_pallas_round_ext_fn(
         val,
         dst_local,
         rows_g,
+        row_last,
         rows_loc,
         send,
         recv,
@@ -810,7 +836,8 @@ def frontier_pallas_round_ext_fn(
         feat = x_ext.shape[1:]
         x_loc = x_ext[gidx]
         x_out, ef_out = rnd(
-            x_loc, ef, src_loc, val, dst_local, rows_g, rows_loc, send, recv, q
+            x_loc, ef, src_loc, val, dst_local, rows_g, row_last, rows_loc, send,
+            recv, q,
         )
         owned = x_out.reshape((-1,) + feat)[oflat]
         return jnp.concatenate([owned, x_ext[-1:]]), ef_out
@@ -825,6 +852,7 @@ def frontier_plan_args(sched: DeviceSchedule, plan: FrontierPlan) -> tuple:
         sched.val,
         sched.dst_local,
         sched.rows,
+        sched.row_last,
         plan.rows_loc,
         plan.send_idx,
         plan.recv_idx,

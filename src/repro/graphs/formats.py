@@ -11,9 +11,10 @@ Two layouts:
   engine processes vertices in ``S`` *commit steps* per round; commit step
   ``s`` covers chunk ``s`` (of size ``delta``) of every worker's block
   simultaneously (see DESIGN.md §5).  The schedule stores, for every
-  ``(step, worker)`` cell, a padded edge list so each commit step is a single
-  static-shape gather / segment-reduce / scatter.  Padding entries carry the
-  semiring's annihilating edge value so they contribute the ⊕-identity.
+  ``(step, worker)`` cell, a padded edge list in CSR order, so each commit
+  step is a single static-shape gather / sorted segment-reduce / scatter.
+  Padding entries carry the semiring's annihilating edge value so they
+  contribute the ⊕-identity.
 """
 
 from __future__ import annotations
@@ -29,6 +30,8 @@ __all__ = [
     "assemble_stripe_schedule",
     "build_stripe_schedule",
     "build_worker_stripe",
+    "longest_row",
+    "scan_passes",
 ]
 
 
@@ -143,9 +146,12 @@ class StripeSchedule:
     * ``src[S, P, M]``       — source vertex gathered from the frontier.
     * ``val[S, P, M]``       — edge value (``pad_val`` on padding entries).
     * ``dst_local[S, P, M]`` — destination row *within the cell*, in
-      ``[0, delta]`` where ``delta`` is the dump slot for padding.
+      ``[0, delta]`` where ``delta`` is the dump slot for padding;
+      non-decreasing along ``M`` (edges in CSR order, padding last).
     * ``rows[S, P, delta]``  — global row id of each cell row (``n_slots - 1``
       = dump slot for rows beyond the worker's block).
+    * ``row_last[S, P, delta]`` — slot of each cell row's last edge, ``-1``
+      for a row with no in-edges (where the sorted segment-⊕ reads its total).
 
     The frontier vector used by the engine has length ``n_slots = n + 1``;
     index ``n`` is a write-only dump slot.
@@ -160,8 +166,15 @@ class StripeSchedule:
     val: np.ndarray  # (S, P, M) value dtype
     dst_local: np.ndarray  # (S, P, M) int32
     rows: np.ndarray  # (S, P, delta) int32
+    row_last: np.ndarray  # (S, P, delta) int32
     block_bounds: np.ndarray  # (P + 1,) int64 — contiguous vertex blocks
     edges: int  # true edge count (before padding)
+    longest_row: int  # most edges of any one row in a cell (largest in-degree)
+
+    @property
+    def passes(self) -> int:
+        """Scan passes the sorted segment-⊕ needs: ``⌈log₂ longest_row⌉``."""
+        return scan_passes(self.longest_row)
 
     @property
     def n_slots(self) -> int:
@@ -216,6 +229,25 @@ def build_stripe_schedule(
     return assemble_stripe_schedule(graph, block_bounds, delta, pad_val, stripes)
 
 
+def longest_row(row_last: np.ndarray) -> int:
+    """Most slots any row holds in its cell, read from ``row_last (..., δ)``.
+
+    Rows of a cell are laid out in order, so a row's length is its last slot
+    less the last slot of the nearest non-empty row before it.
+    """
+    rl = np.asarray(row_last, dtype=np.int64)
+    if rl.size == 0:
+        return 0
+    ends = np.maximum.accumulate(rl, axis=-1)
+    prev = np.concatenate([np.full(rl.shape[:-1] + (1,), -1), ends[..., :-1]], -1)
+    return int(max((rl - prev).max(), 0))
+
+
+def scan_passes(longest: int) -> int:
+    """``⌈log₂ longest⌉``: doubling passes that span a row of ``longest`` slots."""
+    return max(int(longest) - 1, 0).bit_length()
+
+
 def build_worker_stripe(
     graph: CSRGraph, lo: int, hi: int, S: int, delta: int, pad_val
 ) -> dict:
@@ -226,9 +258,9 @@ def build_worker_stripe(
     sources/values of those rows), ``n``, ``S``, ``delta``, and ``pad_val`` —
     so a stripe can be content-addressed and reused across graph mutations
     that never touch this block.  Arrays are ``(S, M_w)`` with the worker's
-    own padded width ``M_w``; :func:`assemble_stripe_schedule` pads to the
-    global ``M`` with the same fill convention, bit-identically to a
-    monolithic build.
+    own padded width ``M_w``, except ``row_last`` ``(S, delta)``;
+    :func:`assemble_stripe_schedule` pads to the global ``M`` with the same
+    fill convention, bit-identically to a monolithic build.
     """
     indptr = graph.indptr
     r0s = [min(lo + s * delta, hi) for s in range(S)]
@@ -240,6 +272,7 @@ def build_worker_stripe(
     val = np.full((S, M_w), pad_val, dtype=graph.values.dtype)
     dst_local = np.full((S, M_w), delta, dtype=np.int32)  # dump slot
     rows = np.full((S, delta), graph.n, dtype=np.int32)  # dump slot of frontier
+    row_last = np.full((S, delta), -1, dtype=np.int32)  # empty row
     for s, (r0, r1) in enumerate(zip(r0s, r1s)):
         if r1 <= r0:
             continue
@@ -248,10 +281,18 @@ def build_worker_stripe(
         src[s, :m] = graph.indices[e0:e1]
         val[s, :m] = graph.values[e0:e1]
         # destination row within the cell for each edge
-        row_of_edge = np.repeat(np.arange(r0, r1), np.diff(indptr[r0 : r1 + 1])) - r0
+        deg = np.diff(indptr[r0 : r1 + 1])
+        row_of_edge = np.repeat(np.arange(r0, r1), deg) - r0
         dst_local[s, :m] = row_of_edge.astype(np.int32)
         rows[s, : r1 - r0] = np.arange(r0, r1, dtype=np.int32)
-    return {"src": src, "val": val, "dst_local": dst_local, "rows": rows}
+        row_last[s, : r1 - r0] = np.where(deg > 0, indptr[r0 + 1 : r1 + 1] - e0 - 1, -1)
+    return {
+        "src": src,
+        "val": val,
+        "dst_local": dst_local,
+        "rows": rows,
+        "row_last": row_last,
+    }
 
 
 def assemble_stripe_schedule(
@@ -274,12 +315,14 @@ def assemble_stripe_schedule(
     val = np.full((S, P, M), pad_val, dtype=val_dtype)
     dst_local = np.full((S, P, M), delta, dtype=np.int32)  # dump slot
     rows = np.full((S, P, delta), n, dtype=np.int32)
+    row_last = np.full((S, P, delta), -1, dtype=np.int32)
     for w, st in enumerate(stripes):
         m = st["src"].shape[1]
         src[:, w, :m] = st["src"]
         val[:, w, :m] = st["val"]
         dst_local[:, w, :m] = st["dst_local"]
         rows[:, w, :] = st["rows"]
+        row_last[:, w, :] = st["row_last"]
 
     return StripeSchedule(
         n=n,
@@ -291,6 +334,8 @@ def assemble_stripe_schedule(
         val=val,
         dst_local=dst_local,
         rows=rows,
+        row_last=row_last,
         block_bounds=block_bounds,
         edges=graph.nnz,
+        longest_row=longest_row(row_last),
     )

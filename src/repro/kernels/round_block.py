@@ -21,8 +21,10 @@ is driven directly by the engine's ``(S, P, M)`` stripe layout — the same
   VMEM while the frontier and any row-update constants stay VMEM-resident
   (index_map → 0 for the whole grid);
 * the kernel body runs the *same* semiring ops as the XLA commit step
-  (⊗, per-worker segment-⊕, ``row_update``, publish scatter), which is what
-  makes the parity bar bit-identical rather than merely allclose.
+  (:func:`repro.core.semiring.edge_products`,
+  :func:`~repro.core.semiring.sorted_segment_reduce`, ``row_update``,
+  publish scatter), which is what makes the parity bar bit-identical rather
+  than merely allclose.
 
 ``row_update`` is an arbitrary callable and may close over device arrays
 (Jacobi's ``b/diag`` table, a PPR teleport vector).  Pallas kernels cannot
@@ -41,7 +43,7 @@ from jax.core import eval_jaxpr
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.semiring import Semiring
+from repro.core.semiring import Semiring, edge_products, sorted_segment_reduce
 
 __all__ = [
     "fused_halo_step_fn",
@@ -106,7 +108,7 @@ def fused_round_fn_q(
     ``lax.while_loop`` for the fused solve path.
     """
     S, P, M, delta = sched.S, sched.P, sched.M, sched.delta
-    n_slots = sched.n_slots
+    passes, n_slots = sched.passes, sched.n_slots
     interp = resolve_interpret(interpret)
 
     def rnd(x_ext, q):
@@ -131,24 +133,22 @@ def fused_round_fn_q(
         n_consts, n_q = len(c_in), len(q_in)
 
         def kernel(*refs):
-            # refs = (src, val, dst, rows, *consts, *q, x_in, x_out); x_in is
-            # the alias donor — x_ref below is the persistent VMEM frontier.
-            src_ref, val_ref, dst_ref, rows_ref = refs[:4]
-            c_refs = refs[4 : 4 + n_consts]
-            q_refs = refs[4 + n_consts : 4 + n_consts + n_q]
+            # refs = (src, val, dst, rows, row_last, *consts, *q, x_in, x_out);
+            # x_in is the alias donor — x_ref below is the persistent VMEM
+            # frontier.
+            src_ref, val_ref, dst_ref, rows_ref, last_ref = refs[:5]
+            c_refs = refs[5 : 5 + n_consts]
+            q_refs = refs[5 + n_consts : 5 + n_consts + n_q]
             x_ref = refs[-1]
             src = src_ref[0]  # (P, M) — this commit step's edge stripe
             val = val_ref[0]
             dst = dst_ref[0]
             rows = rows_ref[0]  # (P, delta)
             x = x_ref[...]  # reads every prior step's commits
-            val_b = val.reshape(val.shape + (1,) * len(feat))
-            contrib = semiring.mul(x[src], val_b)
-            # Per-worker segment-⊕ into δ + 1 slots (last = padding dump).
-            seg = dst + (jnp.arange(P, dtype=jnp.int32) * (delta + 1))[:, None]
-            reduced = semiring.segment_reduce(
-                contrib.reshape((-1,) + feat), seg.reshape(-1), P * (delta + 1)
-            ).reshape((P, delta + 1) + feat)[:, :delta]
+            contrib = edge_products(semiring, x[src], val, dst)
+            reduced = sorted_segment_reduce(
+                semiring, contrib, dst, last_ref[0], passes
+            )
             old = x[rows]
             c_vals = [c[...].reshape(shape) for c, shape in zip(c_refs, c_shapes)]
             leaves = [r[...].reshape(a.shape) for r, a in zip(q_refs, q_avals)]
@@ -165,6 +165,7 @@ def fused_round_fn_q(
             pl.BlockSpec((1, P, M), lambda s: (s, 0, 0)),
             pl.BlockSpec((1, P, M), lambda s: (s, 0, 0)),
             pl.BlockSpec((1, P, delta), lambda s: (s, 0, 0)),
+            pl.BlockSpec((1, P, delta), lambda s: (s, 0, 0)),
         ]
         resident = [_full_spec(a.shape) for a in (*c_in, *q_in)]
         return pl.pallas_call(
@@ -174,10 +175,19 @@ def fused_round_fn_q(
             out_specs=_full_spec((n_slots,) + feat),
             out_shape=jax.ShapeDtypeStruct((n_slots,) + feat, semiring.dtype),
             # x_ext in ↔ out: commits stay visible across sequential steps
-            input_output_aliases={4 + n_consts + n_q: 0},
+            input_output_aliases={5 + n_consts + n_q: 0},
             interpret=interp,
             compiler_params=_SEQUENTIAL_GRID,
-        )(sched.src, sched.val, sched.dst_local, sched.rows, *c_in, *q_in, x_ext)
+        )(
+            sched.src,
+            sched.val,
+            sched.dst_local,
+            sched.rows,
+            sched.row_last,
+            *c_in,
+            *q_in,
+            x_ext,
+        )
 
     return rnd
 
@@ -191,12 +201,13 @@ def fused_halo_step_fn(
     delta: int,
     L: int,
     H: int,
+    passes: int,
     interpret: bool | None = None,
 ):
     """One owner-computes halo commit step, fused into a single kernel.
 
-    Returns ``(x_loc, src_s, val_s, dst_s, rows_g_s, rows_loc_s, send_s, q)
-    -> (x_loc, send_vals)`` — the per-shard half of one commit step of
+    Returns ``(x_loc, src_s, val_s, dst_s, last_s, rows_g_s, rows_loc_s,
+    send_s, q) -> (x_loc, send_vals)`` — the per-shard half of one commit step of
     :func:`repro.dist.engine_sharded.frontier_pallas_round_fn`: gather,
     ⊗, per-worker segment-⊕, ``row_update``, the owner-computes publish into
     the shard's ``(L,)`` local frontier (input/output-aliased, so the
@@ -213,6 +224,8 @@ def fused_halo_step_fn(
     this kernel ``S`` times per round under ``lax.fori_loop``, exchanging
     halos between invocations.
 
+    ``last_s`` is the step's ``row_last`` slice and ``passes`` the schedule's
+    scan passes (:func:`repro.core.semiring.sorted_segment_reduce`).
     ``rows_loc_s`` are shard-local row slots (dump ``= L - 1``) used for the
     read-modify-write; ``rows_g_s`` are the global row ids ``row_update``
     sees (PPR teleports index ``q`` by global vertex).  ``send_s`` indexes
@@ -221,7 +234,7 @@ def fused_halo_step_fn(
     """
     interp = resolve_interpret(interpret)
 
-    def step(x_loc, src_s, val_s, dst_s, rows_g_s, rows_loc_s, send_s, q):
+    def step(x_loc, src_s, val_s, dst_s, last_s, rows_g_s, rows_loc_s, send_s, q):
         feat = tuple(jnp.shape(x_loc)[1:])  # () vector, (F,) matrix frontier
         q_leaves, q_tree = jax.tree_util.tree_flatten(q)
         q_avals = [
@@ -243,9 +256,9 @@ def fused_halo_step_fn(
         n_consts, n_q = len(c_in), len(q_in)
 
         def kernel(*refs):
-            src_ref, val_ref, dst_ref, rg_ref, rl_ref, snd_ref = refs[:6]
-            c_refs = refs[6 : 6 + n_consts]
-            q_refs = refs[6 + n_consts : 6 + n_consts + n_q]
+            src_ref, val_ref, dst_ref, last_ref, rg_ref, rl_ref, snd_ref = refs[:7]
+            c_refs = refs[7 : 7 + n_consts]
+            q_refs = refs[7 + n_consts : 7 + n_consts + n_q]
             # x is aliased input ↔ output 0; send is output 1.
             x_ref, send_ref = refs[-2], refs[-1]
             src = src_ref[...]  # (P_loc, M) — owned + halo reads, all local
@@ -254,12 +267,10 @@ def fused_halo_step_fn(
             rows_g = rg_ref[...]  # (P_loc, delta) global ids for row_update
             rows_l = rl_ref[...]  # (P_loc, delta) local slots (dump = L - 1)
             x = x_ref[...]
-            val_b = val.reshape(val.shape + (1,) * len(feat))
-            contrib = semiring.mul(x[src], val_b)
-            seg = dst + (jnp.arange(P_loc, dtype=jnp.int32) * (delta + 1))[:, None]
-            reduced = semiring.segment_reduce(
-                contrib.reshape((-1,) + feat), seg.reshape(-1), P_loc * (delta + 1)
-            ).reshape((P_loc, delta + 1) + feat)[:, :delta]
+            contrib = edge_products(semiring, x[src], val, dst)
+            reduced = sorted_segment_reduce(
+                semiring, contrib, dst, last_ref[...], passes
+            )
             old = x[rows_l]
             c_vals = [c[...].reshape(shape) for c, shape in zip(c_refs, c_shapes)]
             leaves = [r[...].reshape(a.shape) for r, a in zip(q_refs, q_avals)]
@@ -273,7 +284,18 @@ def fused_halo_step_fn(
             # Boundary selection for the halo exchange, also in VMEM.
             send_ref[...] = chunk[snd_ref[...]]
 
-        ins = (src_s, val_s, dst_s, rows_g_s, rows_loc_s, send_s, *c_in, *q_in, x_loc)
+        ins = (
+            src_s,
+            val_s,
+            dst_s,
+            last_s,
+            rows_g_s,
+            rows_loc_s,
+            send_s,
+            *c_in,
+            *q_in,
+            x_loc,
+        )
         return pl.pallas_call(
             kernel,
             grid=(1,),

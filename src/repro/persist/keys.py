@@ -49,7 +49,8 @@ __all__ = [
 # Bump to retire every existing cache entry (layout or semantics change).
 # 2: FrontierPlan src_loc/rows_loc went shard-major (D, S, P_loc, ·).
 # 3: the environment part names the device (platform, device_kind).
-CACHE_FORMAT = 3
+# 4: stripes and schedules carry row_last; rounds reduce by sorted scan.
+CACHE_FORMAT = 4
 
 try:  # installed package
     import importlib.metadata

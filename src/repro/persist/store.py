@@ -218,7 +218,7 @@ class SolverCache:
             _read_fault(self._stripe_path(digest))
             with np.load(self._stripe_path(digest), allow_pickle=False) as arrays:
                 out = {k: np.asarray(arrays[k]) for k in arrays.files}
-            if not {"src", "val", "dst_local", "rows"} <= out.keys():
+            if not {"src", "val", "dst_local", "rows", "row_last"} <= out.keys():
                 return None
             return out
         except Exception:

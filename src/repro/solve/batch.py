@@ -78,7 +78,8 @@ def _batched_round(solver, sched, backend: str, frontier: str, feature_dims: int
         )
     if backend == "jit":
         rnd = round_fn_q_dyn(sched, sr, solver._row_update_q)
-        return jax.vmap(rnd, in_axes=(0, 0) + (None,) * 4), schedule_args(sched)
+        sargs = schedule_args(sched)
+        return jax.vmap(rnd, in_axes=(0, 0) + (None,) * len(sargs)), sargs
     if backend == "pallas":
         rnd = round_fn_pallas_q(sched, sr, solver._row_update_q)
         return jax.vmap(rnd, in_axes=(0, 0)), ()
@@ -94,8 +95,9 @@ def _batched_round(solver, sched, backend: str, frontier: str, feature_dims: int
             sched, sr, solver._row_update_q, mesh, axis=solver.mesh_axis,
             feature_dims=feature_dims,
         )
-        vm = jax.vmap(base, in_axes=(0, None, None, None, None, 0))
-        return (lambda X, qb, *args: vm(X, *args, qb)), schedule_args(sched)
+        sargs = schedule_args(sched)
+        vm = jax.vmap(base, in_axes=(0,) + (None,) * len(sargs) + (0,))
+        return (lambda X, qb, *args: vm(X, *args, qb)), sargs
     from repro.dist.engine_sharded import frontier_plan_args, frontier_round_ext_fn
 
     plan = solver.frontier_plan(sched)

@@ -58,6 +58,7 @@ from repro.graphs.formats import (
     CSRGraph,
     assemble_stripe_schedule,
     build_worker_stripe,
+    longest_row,
 )
 from repro.graphs.partition import PARTITION_METHODS, Partition
 from repro.solve.problem import Problem
@@ -87,7 +88,8 @@ HALO_DTYPES = ("f32", "int8", "fp8")
 _FUSED_ROUND_BUILDERS = {"jit": round_fn_q, "pallas": round_fn_pallas_q}
 
 _NO_QUERY = np.zeros((), dtype=np.int32)  # dummy q for query-free problems
-_STRIPES = ("src", "val", "dst_local", "rows")  # the schedule arrays placed on device
+# the schedule arrays placed on device
+_STRIPES = ("src", "val", "dst_local", "rows", "row_last")
 
 
 class Solver:
@@ -196,6 +198,10 @@ class Solver:
             "compile_time_s": 0.0,
             "cache_loads": 0,
             "degradations": 0,
+            # the newest schedule's sorted segment-⊕: doubling passes per
+            # commit step, and the longest row they have to span
+            "scan_passes": None,
+            "longest_row": None,
         }
         self.reprobe_every = reprobe_every
         self._obs_since_refit = 0
@@ -447,13 +453,15 @@ class Solver:
 
         A miss is the span ``repro.schedule.build``: the schedule is made on
         the host, then placed on the device in its child span
-        ``repro.schedule.put``, which ends once the stripes are there.
+        ``repro.schedule.put``, which ends once the stripes are there.  The
+        span's attributes ``scan_passes`` and ``longest_row`` (also in
+        ``stats``) say how much work the sorted segment-⊕ does per step.
         """
         delta_eff = self.resolve_delta(delta)
         sched = self._schedules.get(delta_eff)
         if sched is not None:
             return sched
-        with spans.span("repro.schedule.build"):
+        with spans.span("repro.schedule.build") as attrs:
             if self.persist is not None:
                 sched = self.persist.load_schedule(delta_eff, put=np.asarray)
                 if sched is not None:
@@ -480,8 +488,14 @@ class Solver:
                     sched, **{k: put(getattr(sched, k)) for k in _STRIPES}
                 )
                 jax.block_until_ready(schedule_args(sched))
+            attrs.update(scan_passes=sched.passes, longest_row=sched.longest_row)
+        self._record_scan(sched)
         self._schedules[delta_eff] = sched
         return sched
+
+    def _record_scan(self, sched: DeviceSchedule) -> None:
+        self.stats["scan_passes"] = sched.passes
+        self.stats["longest_row"] = sched.longest_row
 
     def _schedule_from_stripes(self, delta_eff: int) -> DeviceSchedule:
         """Assemble the schedule stripe-by-stripe through the shared store.
@@ -804,7 +818,8 @@ class Solver:
         """The fused ``lax.while_loop`` path: ``backend ∈ {"jit", "pallas"}``.
 
         The jit backend compiles the *dynamic-schedule* loop — schedule
-        arrays are call arguments, keyed by their shape class ``(δ, S, M)``
+        arrays are call arguments, keyed by their shape class
+        ``(δ, S, M, passes)``
         — so an :meth:`apply_updates` that patches stripes in place replays
         the same executable with the new arrays, zero retraces.  The pallas
         kernel bakes the schedule into its grid, so it keeps the closure
@@ -815,7 +830,7 @@ class Solver:
         if backend == "jit":
             sargs = schedule_args(sched)
             fn = self.compile_cached(
-                ("dyn", backend, sched.delta, sched.S, sched.M) + fk,
+                ("dyn", backend, sched.delta, sched.S, sched.M, sched.passes) + fk,
                 make_solve_fn_q_dyn(
                     sched, sr, self._row_update_q, self.problem.residual
                 ),
@@ -868,7 +883,8 @@ class Solver:
             # dynamic form: survives same-shape schedule mutations, like jit
             sargs = schedule_args(sched)
             rnd = self.compile_cached(
-                ("dyn", "host", "round", sched.delta, sched.S, sched.M) + fk,
+                ("dyn", "host", "round", sched.delta, sched.S, sched.M, sched.passes)
+                + fk,
                 round_fn_q_dyn(sched, sr, self._row_update_q),
                 x_ext,
                 q,
@@ -898,7 +914,7 @@ class Solver:
                 sched, sr, self._row_update_q, mesh, axis=self.mesh_axis,
                 feature_dims=x_ext.ndim - 1,
             )
-            args = (sched.src, sched.val, sched.dst_local, sched.rows)
+            args = schedule_args(sched)
             compiled = self.compile_cached(
                 ("sharded", "replicated", sched.delta, D) + fk,
                 fn,
@@ -1074,10 +1090,12 @@ class Solver:
     def _patch_schedules(self, report):
         """Rebuild only the touched workers' stripes of every cached schedule.
 
-        A stripe that outgrows the schedule's padded width ``M`` forces that
-        δ's schedule to drop for a lazy full rebuild (global re-padding would
-        touch every worker anyway); otherwise the patched arrays keep their
-        shapes, which is what lets the dyn executables replay compile-free.
+        A stripe that outgrows the schedule's padded width ``M``, or a row
+        longer than its ``2**passes`` scan span, forces that δ's schedule to
+        drop for a lazy full rebuild (global re-padding would touch every
+        worker anyway); otherwise the patched arrays keep their shapes and
+        static metadata, which is what lets the dyn executables replay
+        compile-free.
         """
         from repro.persist.keys import stripe_fingerprint
 
@@ -1091,7 +1109,10 @@ class Solver:
                 st = build_worker_stripe(
                     self._sched_graph, lo, hi, sched.S, delta_eff, pad_val
                 )
-                if st["src"].shape[1] > sched.M:
+                if (
+                    st["src"].shape[1] > sched.M
+                    or longest_row(st["row_last"]) > 2**sched.passes
+                ):
                     fits = False
                     break
                 stripes[int(w)] = st
@@ -1101,6 +1122,7 @@ class Solver:
             src = np.array(sched.src)
             val = np.array(sched.val)
             dst_local = np.array(sched.dst_local)
+            row_last = np.array(sched.row_last)
             for w, st in stripes.items():
                 m = st["src"].shape[1]
                 src[:, w, :] = 0
@@ -1109,16 +1131,21 @@ class Solver:
                 val[:, w, :m] = st["val"]
                 dst_local[:, w, :] = delta_eff
                 dst_local[:, w, :m] = st["dst_local"]
+                row_last[:, w, :] = st["row_last"]
                 # rows[:, w] is untouched: it depends only on (lo, hi, δ, n)
             put = self._schedule_put()
-            self._schedules[delta_eff] = dataclasses.replace(
+            sched = dataclasses.replace(
                 sched,
                 src=put(src),
                 val=put(val),
                 dst_local=put(dst_local),
+                row_last=put(row_last),
                 edges=self._sched_graph.nnz,
                 padding_overhead=src.size / max(self._sched_graph.nnz, 1),
+                longest_row=longest_row(row_last),
             )
+            self._schedules[delta_eff] = sched
+            self._record_scan(sched)
             if self.persist is not None:
                 for w, st in stripes.items():
                     digest = stripe_fingerprint(

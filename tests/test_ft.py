@@ -298,6 +298,7 @@ def _stripe(fill: int) -> dict:
         "val": np.full(8, float(fill), np.float32),
         "dst_local": np.arange(8, dtype=np.int64),
         "rows": np.arange(8, dtype=np.int64),
+        "row_last": np.arange(8, dtype=np.int64),
     }
 
 

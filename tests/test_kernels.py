@@ -7,6 +7,7 @@ match the engine's XLA round bit-for-bit (the contract ``backend="pallas"``
 stands on; see ``tests/test_pallas_backend.py`` for the full solver matrix).
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -194,8 +195,10 @@ class TestFusedRoundEngineIntegration:
                 sched.dst_local[s]
                 + (jnp.arange(sched.P, dtype=jnp.int32) * (sched.delta + 1))[:, None]
             )
-            red = PLUS_TIMES.segment_reduce(
-                contrib.reshape(-1), seg.reshape(-1), sched.P * (sched.delta + 1)
+            red = jax.ops.segment_sum(
+                contrib.reshape(-1),
+                seg.reshape(-1),
+                num_segments=sched.P * (sched.delta + 1),
             ).reshape(sched.P, sched.delta + 1)[:, : sched.delta]
             new = tele + red
             x_j = x_j.at[sched.rows[s].reshape(-1)].set(new.reshape(-1), mode="drop")

@@ -84,8 +84,8 @@ x_ref = rnd(rnd(x0))
 mesh = make_mesh((4,), ("data",))
 srnd = jax.jit(sharded_round_fn(sched, PLUS_TIMES, ru, mesh, axis="data"))
 with jax.set_mesh(mesh):
-    x_s = srnd(srnd(x0, sched.src, sched.val, sched.dst_local, sched.rows),
-               sched.src, sched.val, sched.dst_local, sched.rows)
+    args = (sched.src, sched.val, sched.dst_local, sched.rows, sched.row_last)
+    x_s = srnd(srnd(x0, *args), *args)
 assert float(jnp.abs(x_ref - x_s).max()) == 0.0, "sharded != reference"
 print("OK")
 """

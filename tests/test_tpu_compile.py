@@ -136,7 +136,7 @@ def test_halo_round_compiles_for_four_chips(graphs, topo):
     block = NamedSharding(mesh, P("data", None, None, None))
     cell = NamedSharding(mesh, P(None, "data", None))
     whole = NamedSharding(mesh, P())
-    layout = (block, cell, cell, cell, block, cell, cell, whole, whole)
+    layout = (block, cell, cell, cell, cell, block, cell, cell, whole, whole)
     args = frontier_plan_args(sched, plan)
     specs = [_spec(a, s) for a, s in zip(args, layout)]
     x = _spec(np.zeros(g.n + 1, np.float32), whole)
@@ -183,6 +183,7 @@ def test_fused_halo_step_kernel_lowers_for_v5e(graphs, one_chip):
         delta=sched.delta,
         L=plan.L,
         H=plan.H,
+        passes=sched.passes,
         interpret=False,
     )
     i32 = np.int32
@@ -191,6 +192,7 @@ def test_fused_halo_step_kernel_lowers_for_v5e(graphs, one_chip):
         np.zeros((plan.P_loc, sched.M), i32),
         np.zeros((plan.P_loc, sched.M), np.float32),
         np.zeros((plan.P_loc, sched.M), i32),
+        np.zeros((plan.P_loc, sched.delta), i32),
         np.zeros((plan.P_loc, sched.delta), i32),
         np.zeros((plan.P_loc, sched.delta), i32),
         np.zeros(plan.H, i32),

@@ -79,7 +79,7 @@ def test_scopes_emit_no_instructions(name, monkeypatch):
     bare = _compiled_loop_text(name)
     assert not any(s in bare for s in SCOPES)
     assert scoped == _opcodes(bare)
-    assert scoped["scatter"] >= 2  # the segment-⊕ and the publish
+    assert scoped["scatter"] == 1  # the publish; the segment-⊕ scans, not scatters
 
 
 def _numpy_changed_rows(solver, rounds):
