@@ -797,6 +797,7 @@ class Solver:
                 rounds=result.rounds,
                 changed_rows=result.changed_rows,
                 row_updates=result.rounds * self.graph.n,
+                columns=math.prod(x_ext.shape[1:]),
             )
         return result
 

@@ -24,7 +24,12 @@ import pytest
 from jax.sharding import NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.core.engine import make_schedule, make_solve_fn_q_dyn, schedule_args
+from repro.core.engine import (
+    DeviceSchedule,
+    make_schedule,
+    make_solve_fn_q_dyn,
+    schedule_args,
+)
 from repro.dist.compat import make_mesh
 from repro.dist.engine_sharded import (
     frontier_plan_args,
@@ -33,11 +38,21 @@ from repro.dist.engine_sharded import (
 )
 from repro.graphs.generators import make_graph
 from repro.kernels.round_block import fused_halo_step_fn, fused_round_fn_q
-from repro.solve import Solver, pagerank_problem, ppr_problem, sssp_problem
+from repro.solve import (
+    Solver,
+    pagerank_problem,
+    ppr_problem,
+    rwr_embedding_problem,
+    sssp_problem,
+)
 from repro.solve.batch import _batched_round, _make_open_batch_solve_fn
 
 SCALE, WORKERS, DELTA = 12, 8, 128
 PROBLEMS = {"pagerank": pagerank_problem, "sssp": sssp_problem, "ppr": ppr_problem}
+# The benchmark cell kron.hubppr's schedule on a v5e (GAP kron scale 18, δ=16384,
+# 8 workers; seed 2147483011): its static shapes, for a compile without the graph.
+HUB = {"n": 2**18, "S": 3, "M": 499_707, "passes": 15, "F": 16}
+V5E_HBM_BYTES = 16e9
 
 
 @pytest.fixture(scope="module")
@@ -91,6 +106,38 @@ def test_jit_solve_loop_compiles_for_v5e(graphs, one_chip, name):
     stripes = sum(a.nbytes for a in schedule_args(sched))
     assert compiled.memory_analysis().argument_size_in_bytes >= stripes
     assert "tpu_custom_call" not in compiled.as_text()  # plain XLA, no kernel
+
+
+def test_hub_matrix_solve_loop_compiles_for_v5e(one_chip):
+    """The (n, 16) hub-PPR solve loop at the cell's shapes fits one chip.
+
+    The compiler keeps the ``(P, M, 16)`` slot arrays with the slot axis
+    minor and hands the frontier in and out with the vertex axis minor, so
+    neither is padded from 16 lanes to 128 (inside the loop it keeps an
+    8x padded row-major copy of the frontier, 134 MB at this size).
+    """
+    n, S, M, F = HUB["n"], HUB["S"], HUB["M"], HUB["F"]
+    sched = DeviceSchedule(
+        n=n, P=WORKERS, delta=16384, S=S, M=M, src=None, val=None,
+        dst_local=None, rows=None, row_last=None, edges=0, padding_overhead=1.0,
+        passes=HUB["passes"], longest_row=2 ** HUB["passes"],
+    )
+    problem = rwr_embedding_problem(feature_dim=F)
+    fn = make_solve_fn_q_dyn(
+        sched, problem.semiring, problem.make_row_update(None), problem.residual
+    )
+    i32, f32 = jnp.int32, jnp.float32
+    shapes = [
+        ((n + 1, F), f32), ((n, F), f32),
+        ((S, WORKERS, M), i32), ((S, WORKERS, M), f32), ((S, WORKERS, M), i32),
+        ((S, WORKERS, 16384), i32), ((S, WORKERS, 16384), i32), ((), f32), ((), i32),
+    ]
+    specs = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    mem = jax.jit(fn).lower(*specs).compile().memory_analysis()
+    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert peak < V5E_HBM_BYTES
+    assert mem.output_size_in_bytes < 1.01 * (n + 1) * F * 4  # not lane-padded
 
 
 @pytest.mark.parametrize("name", ["sssp", "ppr"])

@@ -22,7 +22,7 @@ import repro.core.engine as engine
 from repro import spans
 from repro.core.engine import make_solve_fn_q_dyn, schedule_args
 from repro.graphs.generators import make_graph
-from repro.solve import Solver, pagerank_problem, sssp_problem
+from repro.solve import Solver, pagerank_problem, rwr_embedding_problem, sssp_problem
 
 SCOPES = ("commit.gather", "commit.segment_reduce", "commit.publish")
 GRAPHS = {
@@ -223,8 +223,32 @@ def test_solver_records_setup_and_solves():
         "rounds": res.rounds,
         "changed_rows": res.changed_rows,
         "row_updates": res.rounds * GRAPHS["sssp"].n,
+        "columns": 1,
     }
     assert solver.stats["schedule_builds"] == 1
+
+
+@pytest.mark.parametrize("F", [1, 3])
+def test_matrix_solve_records_columns_and_counts_rows_once(F):
+    g = GRAPHS["pagerank"]
+    solver = Solver(g, rwr_embedding_problem(F), n_workers=4, delta=64, min_chunk=16)
+    x = solver._x_ext(None)
+    assert x.shape == (g.n + 1, F)
+    rnd = solver.round_callable(backend="host")
+    res = solver.solve(backend="jit")
+    (solve,) = spans.records("repro.solve")[-1:]
+    assert solve.attrs["columns"] == F
+    assert solve.attrs["row_updates"] == res.rounds * g.n
+    changed = entries = 0
+    for _ in range(res.rounds):
+        x_new = rnd(x)
+        ne = np.asarray(x_new)[:-1] != np.asarray(x)[:-1]
+        changed += int(ne.any(axis=1).sum())
+        entries += int(ne.sum())
+        x = x_new
+    assert solve.attrs["changed_rows"] == res.changed_rows == changed
+    assert 0 < changed <= res.rounds * g.n
+    assert entries > changed if F > 1 else entries == changed
 
 
 def test_schedule_from_persisted_store_is_placed(tmp_path):
